@@ -666,6 +666,8 @@ def delta_serving_benchmarks(slot_counts: tuple = (4, 6),
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     micro_benchmarks()
     print()
     from benchmarks import (ablation_lambda, fig2, roofline, seeds, table1,

@@ -72,7 +72,7 @@ def audit_models() -> list[tuple[str, Any, dict]]:
     from repro.configs.base import RuntimeConfig, get_arch, reduced
     from repro.models.model import Model
 
-    rt = RuntimeConfig(remat=False, seq_chunk=32, use_pallas=False)
+    rt = RuntimeConfig(remat=False, seq_chunk=32)
     dense = reduced(get_arch("tinyllama_1_1b"), n_layers=4, d_model=64)
     ssm = reduced(get_arch("mamba2_370m"), n_layers=4, d_model=64)
     bf16 = dataclasses.replace(dense, dtype="bfloat16")

@@ -359,8 +359,8 @@ def unhashable_static(sf: SourceFile, ctx: Context):
 @register_rule(
     "kernel-parity",
     "Every Pallas kernel module ships a pure-jnp fallback (`*_jnp`) "
-    "selected off-TPU via the RuntimeConfig.use_pallas / ops mode "
-    "dispatch, and a parity test in tests/test_kernels.py pins the two "
+    "selected off-TPU by the platform dispatch in kernels/ops.py, "
+    "and a parity test in tests/test_kernels.py pins the two "
     "against each other — TPU-only code paths must never be the only "
     "implementation of round math.")
 def kernel_parity(sf: SourceFile, ctx: Context):
@@ -395,7 +395,7 @@ def kernel_parity(sf: SourceFile, ctx: Context):
         yield Finding(
             sf.rel, line, "kernel-parity",
             f"{base} is not referenced by {cfg.kernel_dispatch} — the "
-            f"kernel is unreachable from the use_pallas mode dispatch")
+            f"kernel is unreachable from the platform dispatch")
     tests_src = ctx.read_rel(cfg.kernel_tests)
     if tests_src is None or stem not in tests_src:
         yield Finding(

@@ -253,7 +253,6 @@ class RuntimeConfig:
     multi_pod: bool = False
     zero3: bool = True               # shard frozen base over the client axes
     remat: bool = True               # activation checkpointing per block
-    use_pallas: bool = False         # use Pallas kernels (TPU) vs jnp reference
     seq_chunk: int = 1024            # query-chunk for lax attention (prefill)
     unified_selection: bool = True   # static union layer set per round (server-regulated)
 

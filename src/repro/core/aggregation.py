@@ -93,8 +93,10 @@ def aggregate_stacked(deltas: PyTree, weights: Array, cfg) -> PyTree:
 
 
 def apply_update(params: PyTree, update: PyTree, lr: float) -> PyTree:
-    """Eq. (6): θ^{t+1} = θ^t − η Δ^t."""
-    return jax.tree.map(lambda p, u: (p - lr * u.astype(p.dtype)), params, update)
+    """Eq. (6): θ^{t+1} = θ^t − η Δ^t, in the params' own dtype (a traced
+    f32 ``lr`` would otherwise promote bf16 params to f32)."""
+    return jax.tree.map(lambda p, u: (p - lr * u.astype(p.dtype)
+                                      ).astype(p.dtype), params, update)
 
 
 # ---------------------------------------------------------------------------
@@ -204,7 +206,7 @@ def apply_update_suffix(params: PyTree, update: PyTree, lr: float, cut: int,
         c = cuts[key]
 
         def upd(p, u, c=c):
-            new = p[c:] - lr * u.astype(p.dtype)
+            new = (p[c:] - lr * u.astype(p.dtype)).astype(p.dtype)
             return new if c == 0 else jnp.concatenate([p[:c], new], axis=0)
 
         out[key] = jax.tree.map(upd, sub, update[key])

@@ -270,11 +270,6 @@ class Client:
         self._probe_cohort = suite["probe_cohort"]
         self._probe_update_cohort = suite["probe_update_cohort"]
         self._probe_update_cohort_masked = suite["probe_update_cohort_masked"]
-        # kernel dispatch for the masked hot path: the real Pallas kernels
-        # only when the runtime opts in (TPU), the bit-identical jnp
-        # fallback otherwise — pallas interpret mode inside a vmapped τ-scan
-        # would dominate the round on CPU
-        self._kernel_mode = "pallas" if model.runtime.use_pallas else "jnp"
 
     # -- Eq. (3)-(4): τ masked SGD steps, return accumulated update ---------
     def _local_update_impl(self, params: PyTree, batches: PyTree,
@@ -284,7 +279,9 @@ class Client:
         def step(p, batch):
             loss, g = jax.value_and_grad(model.loss)(p, batch)
             g = apply_layer_mask(g, mask, cfg)
-            new_p = jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype), p, g)
+            new_p = jax.tree.map(
+                lambda a, b: (a - lr * b.astype(a.dtype)).astype(a.dtype),
+                p, g)
             return new_p, loss
 
         p_final, losses = jax.lax.scan(step, params, batches)
@@ -399,14 +396,13 @@ class Client:
             return params, jax.vmap(one)(batches)
 
         tr0 = trainable_slice(params, cut, cfg)
-        mode = self._kernel_mode
 
         def one(b, m):
             def step(tr, batch):
                 loss, g = jax.value_and_grad(
                     lambda t: model.loss(params, batch, trainable=t,
                                          cut=cut))(tr)
-                new_tr = masked_suffix_sgd(tr, g, m, lr, cut, cfg, mode=mode)
+                new_tr = masked_suffix_sgd(tr, g, m, lr, cut, cfg)
                 return new_tr, loss
 
             tr_fin, losses = jax.lax.scan(step, tr0, b)
@@ -476,11 +472,9 @@ class Client:
             # fallback — the probe itself stays dense across all L layers:
             # next round's selection needs utilities for every layer,
             # trained or not (DESIGN.md §7)
-            out["grad_sq_norms"] = M.per_layer_sq_norms(
-                g, self.cfg, mode=self._kernel_mode)
+            out["grad_sq_norms"] = M.per_layer_sq_norms(g, self.cfg)
         if "param_sq_norms" in reqs:
-            out["param_sq_norms"] = M.per_layer_param_sq_norms(
-                params, self.cfg, mode=self._kernel_mode)
+            out["param_sq_norms"] = M.per_layer_param_sq_norms(params, self.cfg)
         return {k: v for k, v in out.items() if k in reqs}
 
     def probe(self, params, batch,

@@ -93,10 +93,9 @@ def per_layer_sq_norms(grads: Any, cfg, *, mode: str | None = None,
     return jnp.concatenate(parts)
 
 
-def per_layer_param_sq_norms(params: Any, cfg, *, mode: str | None = None,
-                             interpret: bool | None = None) -> Array:
+def per_layer_param_sq_norms(params: Any, cfg) -> Array:
     """‖θ_l‖² per layer (for the RGN baseline)."""
-    return per_layer_sq_norms(params, cfg, mode=mode, interpret=interpret)
+    return per_layer_sq_norms(params, cfg)
 
 
 def per_layer_stats(grads: Any, cfg) -> tuple[Array, Array, Array]:

@@ -1,7 +1,9 @@
 """Public jit'd wrappers over the Pallas kernels.
 
 These adapt model-level pytrees / shapes to the kernels' flat layouts and
-fall back to interpret mode off-TPU (``interpret=None`` ⇒ auto-detect).
+pick the implementation by platform (:func:`_resolve_mode`): the Pallas
+kernel on a TPU, the pure-jnp fallback elsewhere.  Tests force either
+with ``mode``; a forced kernel off-TPU runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -102,7 +104,7 @@ def ssd(x, dt, A_log, Bmat, Cmat, D, *, chunk: int = 128,
 # per-layer gradient norms over a stacked pytree
 # ---------------------------------------------------------------------------
 
-def layer_grad_norms(stacked_grads: PyTree, *, block: int = 4096,
+def layer_grad_norms(stacked_grads: PyTree, *, block_rows: int = 512,
                      interpret: Optional[bool] = None,
                      mode: Optional[str] = None) -> jax.Array:
     """Σ over leaves of row-wise ‖·‖² for (L, …) stacked leaves → (L,).
@@ -118,9 +120,10 @@ def layer_grad_norms(stacked_grads: PyTree, *, block: int = 4096,
         L = leaf.shape[0]
         flat = leaf.reshape(L, -1)
         if m == "jnp":
-            sq = _lgn.layer_sq_norms_2d_jnp(flat, block=block)
+            sq = _lgn.layer_sq_norms_2d_jnp(flat, block_rows=block_rows)
         else:
-            sq = _lgn.layer_sq_norms_2d(flat, block=block, interpret=it)
+            sq = _lgn.layer_sq_norms_2d(flat, block_rows=block_rows,
+                                        interpret=it)
         total = sq if total is None else total + sq
     return total
 
@@ -130,7 +133,7 @@ def layer_grad_norms(stacked_grads: PyTree, *, block: int = 4096,
 # ---------------------------------------------------------------------------
 
 def masked_sgd_update(stacked_params: PyTree, stacked_grads: PyTree,
-                      mask: jax.Array, lr, *, block: int = 4096,
+                      mask: jax.Array, lr, *, block_rows: int = 512,
                       interpret: Optional[bool] = None,
                       mode: Optional[str] = None) -> PyTree:
     """Fused Eq.(3) apply θ_l ← θ_l − η·m(l)·g_l over a stacked pytree.
@@ -147,7 +150,8 @@ def masked_sgd_update(stacked_params: PyTree, stacked_grads: PyTree,
             return _mu.masked_sgd_update_2d_jnp(p, g, mask, lr)
         L = p.shape[0]
         out = _mu.masked_sgd_update_2d(p.reshape(L, -1), g.reshape(L, -1),
-                                       mask, lr, block=block, interpret=it)
+                                       mask, lr, block_rows=block_rows,
+                                       interpret=it)
         return out.reshape(p.shape)
 
     return jax.tree.map(upd, stacked_params, stacked_grads)
@@ -157,7 +161,7 @@ def masked_sgd_update(stacked_params: PyTree, stacked_grads: PyTree,
 # fused base + per-slot delta matmul (personalized-delta serving)
 # ---------------------------------------------------------------------------
 
-def base_delta_matmul(x, w, dw, slots, *, block_f=None,
+def base_delta_matmul(x, w, dw, slots, *, block_f: int = 512,
                       interpret: Optional[bool] = None,
                       mode: Optional[str] = None):
     """``y[b] = x[b] @ w + Σ_{e: slots[e]==b} x[b] @ dw[e]`` — the serving
@@ -166,7 +170,8 @@ def base_delta_matmul(x, w, dw, slots, *, block_f=None,
     x: (B, 1, d) decode activations (or (B, d)); w: (d, f) shared base
     weight; dw: (C, d, f) capacity-C per-layer delta entries; slots: (C,)
     int32 slot owner per entry, -1 = empty.  The Pallas kernel on TPU, the
-    bit-identical pure-jnp fallback elsewhere (``mode`` forces either).
+    pure-jnp fallback elsewhere (``mode`` forces either; the two agree to
+    f32 accumulation order).
     """
     m = _resolve_mode(mode, interpret)
     squeeze = x.ndim == 3
@@ -176,7 +181,7 @@ def base_delta_matmul(x, w, dw, slots, *, block_f=None,
     else:
         x2 = x
     if m == "jnp":
-        out = _dmm.base_delta_matmul_2d_jnp(x2, w, dw, slots, block_f=block_f)
+        out = _dmm.base_delta_matmul_2d_jnp(x2, w, dw, slots)
     else:
         out = _dmm.base_delta_matmul_2d(x2, w, dw, slots, block_f=block_f,
                                         interpret=_auto_interpret(interpret))

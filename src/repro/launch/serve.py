@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import RuntimeConfig, get_arch, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.serve import DeltaOverlay, DeltaStore, serve_suite, stack_tree
 
@@ -269,6 +270,7 @@ def main():
     ap.add_argument("--delta-layers", type=int, default=2)
     ap.add_argument("--delta-capacity", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced(get_arch(args.arch))
     model = Model(cfg, RuntimeConfig(remat=False, seq_chunk=32))

@@ -22,6 +22,7 @@ from repro.api.strategy import SelectionContext, get_strategy
 from repro.configs.base import RuntimeConfig, get_arch, reduced
 from repro.core.strategies import ProbeReport
 from repro.data.synthetic import FederatedTaskConfig, SyntheticFederatedData
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import Model
 from repro.sharding.fl_step import make_fl_train_step
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--production", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     # resolve the strategy up front: unknown names fail fast with the
     # registered list + nearest-match suggestion

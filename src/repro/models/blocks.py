@@ -10,8 +10,8 @@ Attention has two execution paths:
 * ``full``   — plain einsum softmax, used for short sequences;
 * ``chunked``— lax.scan over query chunks (memory O(chunk·S) instead of
   O(S²)); this is the XLA-native "flash" path used for prefill_32k.  The
-  Pallas kernel in :mod:`repro.kernels.flash_attention` is the TPU-optimised
-  equivalent, selected via ``RuntimeConfig.use_pallas``.
+  Pallas kernel in :mod:`repro.kernels.flash_attention` is not called from
+  the models: both paths above run on every platform.
 """
 from __future__ import annotations
 
@@ -234,8 +234,7 @@ def attention_fwd(p: dict, x: Array, cfg: ArchConfig, *,
                   causal: bool = True, window: int = 0, prefix_len: int = 0,
                   cross_kv: Optional[tuple] = None, seq_chunk: int = 1024,
                   remat_chunk: bool = False, delta: Optional[dict] = None,
-                  delta_slots: Optional[Array] = None,
-                  delta_mode: str = "jnp"):
+                  delta_slots: Optional[Array] = None):
     """One attention sub-block (pre-norm, residual added by caller).
 
     cache: {"k": (B,W,Kh,hd), "v": ..., "pos": (W,) int32} — decode mode
@@ -258,7 +257,7 @@ def attention_fwd(p: dict, x: Array, cfg: ArchConfig, *,
     def proj(h_, name):
         if delta is not None and name in delta:
             return _kops.base_delta_matmul(h_, p[name], delta[name],
-                                           delta_slots, mode=delta_mode)
+                                           delta_slots)
         return h_ @ p[name]
 
     ln = p["ln"]
@@ -365,14 +364,13 @@ def mlp_param_shapes(cfg: ArchConfig, d_ff: Optional[int] = None) -> dict:
 
 def mlp_fwd(p: dict, x: Array, cfg: ArchConfig, *,
             delta: Optional[dict] = None,
-            delta_slots: Optional[Array] = None,
-            delta_mode: str = "jnp") -> Array:
+            delta_slots: Optional[Array] = None) -> Array:
     from repro.kernels import ops as _kops
 
     def proj(h_, name):
         if delta is not None and name in delta:
             return _kops.base_delta_matmul(h_, p[name], delta[name],
-                                           delta_slots, mode=delta_mode)
+                                           delta_slots)
         return h_ @ p[name]
 
     ln = p["ln"]

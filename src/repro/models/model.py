@@ -295,7 +295,7 @@ def _maybe_remat(fn, runtime: RuntimeConfig):
 def _dense_block_fwd(p: dict, x: Array, cfg: ArchConfig, *, positions,
                      causal, window, prefix_len, seq_chunk,
                      cache=None, cache_pos=None, cross_kv=None,
-                     remat_chunk=False, delta=None, delta_mode="jnp"):
+                     remat_chunk=False, delta=None):
     # delta: (slots (C,), {leaf_name: (C, *shape)}) — this layer's row of
     # the per-slot serving overlay; leaf names are split by sub-block prefix
     dslots = dattn = dmlp = None
@@ -307,7 +307,7 @@ def _dense_block_fwd(p: dict, x: Array, cfg: ArchConfig, *, positions,
         _take(p, "attn_"), x, cfg, positions=positions, cache=cache,
         cache_pos=cache_pos, causal=causal, window=window,
         prefix_len=prefix_len, seq_chunk=seq_chunk, remat_chunk=remat_chunk,
-        delta=dattn, delta_slots=dslots, delta_mode=delta_mode)
+        delta=dattn, delta_slots=dslots)
     x = x + attn_out
     if "xattn_ln" in p:   # whisper decoder cross-attention
         xo, _ = B.attention_fwd(_take(p, "xattn_"), x, cfg, positions=positions,
@@ -315,7 +315,7 @@ def _dense_block_fwd(p: dict, x: Array, cfg: ArchConfig, *, positions,
                                 seq_chunk=seq_chunk)
         x = x + xo
     x = x + B.mlp_fwd(_take(p, "mlp_"), x, cfg, delta=dmlp,
-                      delta_slots=dslots, delta_mode=delta_mode)
+                      delta_slots=dslots)
     return x, new_kv
 
 
@@ -789,16 +789,13 @@ class Model:
         w = window or cfg.sliding_window
 
         if cfg.family in ("dense", "vlm"):
-            dmode = "pallas" if rt.use_pallas else "jnp"
-
             def step(carry, inp):
                 p, kv = inp[:2]
                 dl = (inp[2], inp[3]) if delta is not None else None
                 h, new_kv = _dense_block_fwd(p, carry, cfg, positions=positions,
                                              causal=True, window=w, prefix_len=0,
                                              seq_chunk=rt.seq_chunk, cache=kv,
-                                             cache_pos=pos, delta=dl,
-                                             delta_mode=dmode)
+                                             cache_pos=pos, delta=dl)
                 return h, new_kv
             xs = (params["blocks"], cache["blocks"])
             if delta is not None:

@@ -43,25 +43,6 @@ from repro.sharding import rules
 PyTree = Any
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names, check_vma):
-    """``jax.shard_map`` compat: old jax exposes it under jax.experimental
-    with ``check_rep``/``auto`` instead of ``check_vma``/``axis_names``.
-
-    On old jax the partial-manual form (auto over 'model') trips an XLA
-    SPMD-partitioner check (``IsManualSubgroup`` mismatch, observed on
-    0.4.37 CPU), so the fallback goes FULLY manual: the model axis carries
-    no spec members in the client-only in_specs, every model coordinate
-    runs the same replicated per-client compute, and the client-axis psums
-    are untouched — identical values, just no tensor parallelism."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def shard_cohort_rows(mesh, rows: PyTree) -> PyTree:
     """Place per-cohort-member rows on the mesh, leading (cohort) axis
     sharded over the client axes — the DESIGN.md §4 mapping (one cohort
@@ -364,7 +345,7 @@ def make_fl_train_step(model: Model, mesh, *, zero3: bool = True,
         cl = P(caxes)
         b_spec = P(caxes)        # shard only the leading (clients,) dim
 
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             lambda p, b, m, sz, lr_: step(p, specs, b, m, sz, lr_),
             mesh=mesh,
             in_specs=(p_manual,
@@ -529,7 +510,7 @@ def make_fl_train_step_tau(model: Model, mesh, *, sel_idx: tuple[int, ...],
                                 is_leaf=lambda x: isinstance(x, P))
         cl = P(caxes)
         b_spec = P(caxes)
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             lambda p, b, m, sz, lr_: step(p, specs, b, m, sz, lr_),
             mesh=mesh,
             in_specs=(p_manual,

@@ -202,7 +202,7 @@ KERNEL_GOOD = {
         def foo_jnp(x):
             return x
         """,
-    "kernels/ops.py": "# dispatches foo via use_pallas\n",
+    "kernels/ops.py": "# dispatches foo by platform\n",
     "tests/test_kernels.py": "# exercises foo and foo_jnp parity\n",
 }
 

@@ -106,3 +106,25 @@ def test_custom_shard_models_bypass_cache():
     Client(sharded)
     stats = client_mod.jit_cache_stats()
     assert stats["misses"] == 1 and stats["uncached"] == 1
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """Entry points keep compiled programs in JAX_COMPILATION_CACHE_DIR when
+    it is set (JAX reads it; nothing is set in code), else in the fixed
+    .jax_cache/ at the repo root."""
+    from repro.launch.compile_cache import REPO_CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = use_compile_cache()
+        if env_dir is None:
+            assert got == str(REPO_CACHE_DIR)
+            assert REPO_CACHE_DIR.parent.joinpath("ROADMAP.md").is_file()
+        else:
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

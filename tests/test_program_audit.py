@@ -138,12 +138,10 @@ def test_donation_tripwire():
 def test_f64_tripwire():
     """An injected double-precision cast must trip dtype-discipline even
     though the program's outputs are f32 again."""
-    from jax.experimental import enable_x64
-
     def leak(x):
         return (x.astype(jnp.float64) * 2.0).sum().astype(jnp.float32)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         f = extract_facts("t/f64", jax.jit(leak), (SDS((8,), jnp.float32),))
     assert "float64" in f.jaxpr_dtypes
     out = check_dtypes({"t/f64": f})

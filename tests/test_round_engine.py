@@ -165,3 +165,25 @@ def test_pipelined_hooks_match_synchronous(world, depth, period):
                                   - np.asarray(b, np.float32)).max()),
         p_pipe, p_sync)))
     assert err < 1e-5, f"hooked pipelined param divergence {err}"
+
+
+@pytest.mark.parametrize("engine", ["sequential", "vectorized"])
+def test_bf16_params_keep_their_dtype(engine):
+    """Published configs are bf16: a round must hand bf16 params back.  The
+    traced f32 learning rate used to promote every updated leaf to f32,
+    which broke the next program's bf16 scan carry (the fused probe after
+    a mask-aware update) and doubled the params' memory."""
+    from dataclasses import replace
+    cfg = replace(reduced(get_arch("xlm_roberta_base"), n_layers=3,
+                          d_model=32), dtype="bfloat16")
+    model = Model(cfg, RuntimeConfig(remat=False, seq_chunk=16))
+    params = model.init(jax.random.PRNGKey(0))
+    task = FederatedTaskConfig(
+        n_clients=6, n_classes=10, vocab_size=cfg.vocab_size, seq_len=8,
+        samples_per_client=8, objective="classification")
+    fl = FLConfig(n_clients=6, cohort_size=3, rounds=2, local_steps=2,
+                  lr=0.01, batch_size=4, budget=1, lam=1.0, seed=1)
+    new, hist = _run(model, params, task, fl, engine)
+    assert len(hist.records) == 2
+    assert jax.tree.map(lambda p: p.dtype, new) == \
+        jax.tree.map(lambda p: p.dtype, params)
