@@ -284,11 +284,13 @@ class Client:
                 p, g)
             return new_p, loss
 
-        p_final, losses = jax.lax.scan(step, params, batches)
-        # Δ_i^t = (θ^{t,0} − θ^{t,τ}) / η  = Σ_k Σ_{l∈L_i} g_{i,l}
-        delta = jax.tree.map(lambda a, b: (a - b).astype(jnp.float32) / lr,
-                             params, p_final)
-        return delta, jnp.mean(losses)
+        with jax.named_scope("local_steps"):
+            p_final, losses = jax.lax.scan(step, params, batches)
+            # Δ_i^t = (θ^{t,0} − θ^{t,τ}) / η  = Σ_k Σ_{l∈L_i} g_{i,l}
+            delta = jax.tree.map(
+                lambda a, b: (a - b).astype(jnp.float32) / lr,
+                params, p_final)
+            return delta, jnp.mean(losses)
 
     def local_update(self, params, batches, mask, lr) -> tuple[PyTree, float]:
         """batches: pytree stacked on axis 0 with length τ."""
@@ -307,9 +309,10 @@ class Client:
 
         # deltas: stacked (n, ...) pytree; losses: (n,)
         deltas, losses = jax.vmap(one)(batches, masks)
-        weights = M.aggregation_weights(masks, sizes)        # (n, L), Eq. 7
-        update = agg.aggregate_stacked(deltas, weights, self.cfg)
-        new_params = agg.apply_update(params, update, lr)
+        with jax.named_scope("aggregate"):
+            weights = M.aggregation_weights(masks, sizes)    # (n, L), Eq. 7
+            update = agg.aggregate_stacked(deltas, weights, self.cfg)
+            new_params = agg.apply_update(params, update, lr)
         return new_params, losses
 
     # -- fault-guarded cohort round: survivor reweighting + finite guard ----
@@ -339,12 +342,13 @@ class Client:
             return self._local_update_impl(params, b, m, lr)
 
         deltas, losses = jax.vmap(one)(batches, masks)
-        deltas = agg.corrupt_delta_rows(deltas, codes, explode_scale)
-        ok = agg.finite_row_mask(deltas, max_delta_sq) * survivors
-        deltas = agg.zero_delta_rows(deltas, ok)
-        weights = M.aggregation_weights(masks, sizes * ok)   # survivors only
-        update = agg.aggregate_stacked(deltas, weights, self.cfg)
-        new_params = agg.apply_update(params, update, lr)
+        with jax.named_scope("aggregate"):
+            deltas = agg.corrupt_delta_rows(deltas, codes, explode_scale)
+            ok = agg.finite_row_mask(deltas, max_delta_sq) * survivors
+            deltas = agg.zero_delta_rows(deltas, ok)
+            weights = M.aggregation_weights(masks, sizes * ok)  # survivors
+            update = agg.aggregate_stacked(deltas, weights, self.cfg)
+            new_params = agg.apply_update(params, update, lr)
         return new_params, losses, ok
 
     def cohort_update_guarded_raw(self, params, batches, masks, sizes, lr,
@@ -393,9 +397,8 @@ class Client:
                 _, losses = jax.lax.scan(step, 0, b)
                 return jnp.mean(losses)
 
-            return params, jax.vmap(one)(batches)
-
-        tr0 = trainable_slice(params, cut, cfg)
+            with jax.named_scope("local_steps"):
+                return params, jax.vmap(one)(batches)
 
         def one(b, m):
             def step(tr, batch):
@@ -410,11 +413,15 @@ class Client:
                                  tr0, tr_fin)
             return delta, jnp.mean(losses)
 
-        deltas, losses = jax.vmap(one)(batches, masks)
-        weights = M.aggregation_weights(masks, sizes)        # (n, L), Eq. 7
-        update = agg.aggregate_stacked_suffix(deltas, weights, cut, self.cfg)
-        new_params = agg.apply_update_suffix(params, update, lr, cut,
-                                             self.cfg)
+        with jax.named_scope("local_steps"):
+            tr0 = trainable_slice(params, cut, cfg)
+            deltas, losses = jax.vmap(one)(batches, masks)
+        with jax.named_scope("aggregate"):
+            weights = M.aggregation_weights(masks, sizes)    # (n, L), Eq. 7
+            update = agg.aggregate_stacked_suffix(deltas, weights, cut,
+                                                  self.cfg)
+            new_params = agg.apply_update_suffix(params, update, lr, cut,
+                                                 self.cfg)
         return new_params, losses
 
     def cohort_update_raw(self, params, batches, masks, sizes, lr,
@@ -460,22 +467,24 @@ class Client:
         not requested are never part of the program (XLA sees only the
         returned outputs).
         """
-        g = jax.grad(self.model.loss)(params, batch)
-        out: dict[str, Array] = {}
-        if "grad_means" in reqs or "grad_vars" in reqs:
-            sq, mean, var = M.per_layer_stats(g, self.cfg)
-            out["grad_sq_norms"] = sq
-            out["grad_means"] = mean
-            out["grad_vars"] = var
-        elif "grad_sq_norms" in reqs:
-            # the fused layer_grad_norm kernel (TPU) / its pinned jnp
-            # fallback — the probe itself stays dense across all L layers:
-            # next round's selection needs utilities for every layer,
-            # trained or not (DESIGN.md §7)
-            out["grad_sq_norms"] = M.per_layer_sq_norms(g, self.cfg)
-        if "param_sq_norms" in reqs:
-            out["param_sq_norms"] = M.per_layer_param_sq_norms(params, self.cfg)
-        return {k: v for k, v in out.items() if k in reqs}
+        with jax.named_scope("probe"):
+            g = jax.grad(self.model.loss)(params, batch)
+            out: dict[str, Array] = {}
+            if "grad_means" in reqs or "grad_vars" in reqs:
+                sq, mean, var = M.per_layer_stats(g, self.cfg)
+                out["grad_sq_norms"] = sq
+                out["grad_means"] = mean
+                out["grad_vars"] = var
+            elif "grad_sq_norms" in reqs:
+                # the fused layer_grad_norm kernel (TPU) / its pinned jnp
+                # fallback — the probe itself stays dense across all L
+                # layers: next round's selection needs utilities for every
+                # layer, trained or not (DESIGN.md §7)
+                out["grad_sq_norms"] = M.per_layer_sq_norms(g, self.cfg)
+            if "param_sq_norms" in reqs:
+                out["param_sq_norms"] = M.per_layer_param_sq_norms(
+                    params, self.cfg)
+            return {k: v for k, v in out.items() if k in reqs}
 
     def probe(self, params, batch,
               reqs: tuple = PROBE_KEYS) -> dict[str, np.ndarray]:
@@ -489,11 +498,13 @@ class Client:
             # accumulate-then-divide in FLServer.probe_round
             return {k: v.mean(0) for k, v in outs.items()}
 
-        stats = jax.vmap(one_client)(batches)
-        if score_fn is not None:
-            # strategy's device-side scoring fused into the same program;
-            # applied to the *meaned* stats, exactly like the host path
-            stats = dict(stats, scores=score_fn(stats))
+        with jax.named_scope("probe"):
+            stats = jax.vmap(one_client)(batches)
+            if score_fn is not None:
+                # strategy's device-side scoring fused into the same
+                # program; applied to the *meaned* stats, exactly like the
+                # host path
+                stats = dict(stats, scores=score_fn(stats))
         return stats
 
     def probe_cohort_raw(self, params, batches, reqs: tuple = PROBE_KEYS,
@@ -563,13 +574,15 @@ class Client:
         batches used to pay for ``model.loss`` *and* a second
         ``forward_seq`` (regression test: tests/test_masked_engine.py)."""
         model = self.model
-        h, aux, prefix_len = model.forward_seq(params, batch)
-        loss = model.loss_from_hidden(params, h, aux, prefix_len, batch)
-        acc = jnp.zeros(())
-        if "label" in batch:
-            logits = model._head(params, jnp.mean(h, axis=1)[:, None])[:, 0]
-            acc = jnp.mean((jnp.argmax(logits, -1)
-                            == batch["label"]).astype(jnp.float32))
+        with jax.named_scope("eval"):
+            h, aux, prefix_len = model.forward_seq(params, batch)
+            loss = model.loss_from_hidden(params, h, aux, prefix_len, batch)
+            acc = jnp.zeros(())
+            if "label" in batch:
+                logits = model._head(params,
+                                     jnp.mean(h, axis=1)[:, None])[:, 0]
+                acc = jnp.mean((jnp.argmax(logits, -1)
+                                == batch["label"]).astype(jnp.float32))
         return loss, acc
 
     def evaluate_raw(self, params, batch):

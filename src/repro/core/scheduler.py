@@ -42,6 +42,13 @@ end-of-run drain is excluded, so ``sum(wall_s)`` ≤ total elapsed run time
 just-dispatched round: round t's record is printed at the end of iteration
 t+1, when its program has long been retired — printing no longer destroys
 the overlap it is reporting on.
+
+Each stage runs inside a ``jax.profiler`` span that carries its round
+(``fl.round`` per iteration; ``fl.select_wait``, ``fl.dispatch``,
+``fl.eval`` on this thread; ``fl.select`` on the solver thread; ``fl.plan``,
+``fl.sample``, ``fl.stats_sync``, ``fl.solve``, ``fl.finalize`` and
+``fl.checkpoint`` in ``FLServer``), so a profiler trace puts the device's
+idle gaps down to a stage; PERF.md §3 lists what reads each.
 """
 from __future__ import annotations
 
@@ -51,6 +58,7 @@ from collections import deque
 import jax
 from concurrent.futures import ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FutureTimeout
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from typing import Any, Optional
 
 from repro.core.server import (FLServer, History, RoundRecord, SampledRound)
@@ -136,7 +144,8 @@ class RoundScheduler:
         server's stats/warm-mask caches — reads of those by the main thread
         are gated on this select having completed (:meth:`_can_plan`)."""
         srv = self.server
-        return srv.select_round(plan, srv._stats_np(stats_dev))
+        with TraceAnnotation("fl.select", round=plan.t):
+            return srv.select_round(plan, srv._stats_np(stats_dev, plan.t))
 
     # -- the round loop ---------------------------------------------------
     def run(self, params: PyTree, T: int, verbose: bool, start: int = 0,
@@ -167,102 +176,130 @@ class RoundScheduler:
                                   thread_name_prefix="p1-solver")
         try:
             for t in range(start, T):
-                t0 = time.time()  # repro: allow[nondeterminism] -- wall_s telemetry only, never an input to round math
-                self._join_late(block=False)
-                plan = sampled.plan
-                # the host solve (stats sync + (P1)) overlaps the in-flight
-                # device program *and* the prefetch below
-                masks_fut = pool.submit(self._select, plan, stats_dev)
-                # lookahead: sample rounds t+1..t+depth whose plans are
-                # cache-free while the solver thread works
-                self._prefetch(T, self.depth)
-                if srv.solver_deadline_s is None:
-                    masks = masks_fut.result()
-                    self._selected_through = t
-                else:
-                    try:
-                        masks = masks_fut.result(
-                            timeout=srv.solver_deadline_s)
-                        self._selected_through = t
-                    except FutureTimeout:
-                        # degrade, don't stall: round t proceeds on the
-                        # warm-start rows while the solve finishes in the
-                        # background (read-only fallback — the solver
-                        # thread stays the store's single writer).
-                        # _selected_through is NOT bumped, so cache-
-                        # dependent plans stay gated until _join_late.
-                        masks = srv._fallback_rows(plan)
-                        self._late = (masks_fut, t)
-                # cache-dependent plans (selection_period > 1, non-refresh)
-                # unblock once select(t) has landed in the stats cache
-                self._prefetch(T, self.depth)
-
-                # mask-aware engine: the static prefix cut is derived from
-                # the just-solved masks, so the update program skips the
-                # frozen layers' backward (None = dense; DESIGN.md §7)
-                cut = srv._cut_for(masks)
-                nxt = self._queue[0] if self._queue else None
-                nstats = None
-                if srv._faults_active:
-                    # fault path (DESIGN.md §12): the guarded round step
-                    # replaces the fused/chained dispatch — ONE extra
-                    # compiled program, survivors/codes as runtime arrays
-                    params, losses = srv._update_round_faulty(
-                        params, sampled, masks)
-                    if nxt is not None and nxt.probe_batches is not None:
-                        nstats = client.probe_cohort_raw(
-                            params, nxt.probe_batches, reqs, score_fn)
-                elif fuse and nxt is not None and \
-                        nxt.probe_batches is not None:
-                    # round t+1's probe rides round t's update program
-                    params, losses, nstats = client.probe_update_cohort_raw(
-                        params, sampled.update_batches, masks, plan.sizes,
-                        fl.lr, nxt.probe_batches, reqs, score_fn, cut=cut)
-                else:
-                    params, losses = client.cohort_update_raw(
-                        params, sampled.update_batches, masks, plan.sizes,
-                        fl.lr, cut=cut)
-                    if nxt is not None and nxt.probe_batches is not None:
-                        # chained on the params future: overlaps the update
-                        # on-device, no host round-trip in between
-                        nstats = client.probe_cohort_raw(
-                            params, nxt.probe_batches, reqs, score_fn)
-                loss_dev, acc_dev = client.evaluate_raw(params, test)
-                pending.append((plan, masks, losses, loss_dev, acc_dev,
-                                time.time() - t0))  # repro: allow[nondeterminism] -- wall_s telemetry only
-                if verbose:
-                    # print up to the *previous* round: its program has
-                    # retired, so materialising it cannot stall the round
-                    # just dispatched (printing used to sync every round)
-                    while printed < len(pending) - 1:
-                        if not isinstance(pending[printed], RoundRecord):
-                            pending[printed] = srv._finalize(pending[printed])
-                        srv._print_round(pending[printed])
-                        printed += 1
-                if t + 1 == self._barrier:
-                    # checkpoint boundary: the prefetch gate drained the
-                    # queue here (no round past the boundary was planned),
-                    # so syncing params + pending records captures exactly
-                    # the synchronous loop's state after round t
-                    self._join_late(block=True)
-                    for i in range(len(pending)):
-                        if not isinstance(pending[i], RoundRecord):
-                            pending[i] = srv._finalize(pending[i])
-                    srv.save_state(params, t + 1,
-                                   History(records=prefix + pending))
-                    self._barrier = self._next_barrier(t + 1, T)
+                with StepTraceAnnotation("fl.round", step_num=t):
+                    t0 = time.time()  # repro: allow[nondeterminism] -- wall_s telemetry only, never an input to round math
+                    self._join_late(block=False)
+                    plan = sampled.plan
+                    # the host solve (stats sync + (P1)) overlaps the
+                    # in-flight device program *and* the prefetch below
+                    masks_fut = pool.submit(self._select, plan, stats_dev)
+                    # lookahead: sample rounds t+1..t+depth whose plans
+                    # are cache-free while the solver thread works
                     self._prefetch(T, self.depth)
-                    if self._queue:
-                        # restart the stream: the boundary round's probe
-                        # runs standalone on the just-saved params (same
-                        # math as the fused/chained dispatch — pinned by
-                        # the engine-parity tests)
-                        sampled = self._queue.popleft()
-                        stats_dev = (client.probe_cohort_raw(
-                            params, sampled.probe_batches, reqs, score_fn)
-                            if sampled.probe_batches is not None else None)
-                elif self._queue:
-                    sampled, stats_dev = self._queue.popleft(), nstats
+                    with TraceAnnotation("fl.select_wait", round=t) as wait:
+                        fallback = False
+                        if srv.solver_deadline_s is None:
+                            masks = masks_fut.result()
+                            self._selected_through = t
+                        else:
+                            try:
+                                masks = masks_fut.result(
+                                    timeout=srv.solver_deadline_s)
+                                self._selected_through = t
+                            except FutureTimeout:
+                                # degrade, don't stall: round t proceeds on
+                                # the warm-start rows while the solve
+                                # finishes in the background (read-only
+                                # fallback — the solver thread stays the
+                                # store's single writer).
+                                # _selected_through is NOT bumped, so cache-
+                                # dependent plans stay gated until _join_late.
+                                masks = srv._fallback_rows(plan)
+                                self._late = (masks_fut, t)
+                                fallback = True
+                        wait.set_metadata(fallback=fallback)
+                    # cache-dependent plans (selection_period > 1,
+                    # non-refresh) unblock once select(t) has landed in
+                    # the stats cache
+                    self._prefetch(T, self.depth)
+
+                    # mask-aware engine: the static prefix cut is derived
+                    # from the just-solved masks, so the update program
+                    # skips the frozen layers' backward (None = dense;
+                    # DESIGN.md §7)
+                    cut = srv._cut_for(masks)
+                    nxt = self._queue[0] if self._queue else None
+                    probe_next = (nxt is not None
+                                  and nxt.probe_batches is not None)
+                    fused = fuse and probe_next and not srv._faults_active
+                    nstats = None
+                    with TraceAnnotation(
+                            "fl.dispatch", round=t,
+                            cut=-1 if cut is None else cut,
+                            probed=len(nxt.plan.probe_ids) if probe_next
+                            else 0, fused=fused):
+                        if srv._faults_active:
+                            # fault path (DESIGN.md §12): the guarded round
+                            # step replaces the fused/chained dispatch —
+                            # ONE extra compiled program, survivors/codes
+                            # as runtime arrays
+                            params, losses = srv._update_round_faulty(
+                                params, sampled, masks)
+                            if probe_next:
+                                nstats = client.probe_cohort_raw(
+                                    params, nxt.probe_batches, reqs,
+                                    score_fn)
+                        elif fused:
+                            # round t+1's probe rides round t's update
+                            # program
+                            params, losses, nstats = \
+                                client.probe_update_cohort_raw(
+                                    params, sampled.update_batches, masks,
+                                    plan.sizes, fl.lr, nxt.probe_batches,
+                                    reqs, score_fn, cut=cut)
+                        else:
+                            params, losses = client.cohort_update_raw(
+                                params, sampled.update_batches, masks,
+                                plan.sizes, fl.lr, cut=cut)
+                            if probe_next:
+                                # chained on the params future: overlaps
+                                # the update on-device, no host round-trip
+                                # in between
+                                nstats = client.probe_cohort_raw(
+                                    params, nxt.probe_batches, reqs,
+                                    score_fn)
+                    with TraceAnnotation("fl.eval", round=t):
+                        loss_dev, acc_dev = client.evaluate_raw(params, test)
+                    pending.append((plan, masks, losses, loss_dev, acc_dev,
+                                    time.time() - t0))  # repro: allow[nondeterminism] -- wall_s telemetry only
+                    if verbose:
+                        # print up to the *previous* round: its program
+                        # has retired, so materialising it cannot stall
+                        # the round just dispatched (printing used to sync
+                        # every round)
+                        while printed < len(pending) - 1:
+                            if not isinstance(pending[printed], RoundRecord):
+                                pending[printed] = srv._finalize(
+                                    pending[printed])
+                            srv._print_round(pending[printed])
+                            printed += 1
+                    if t + 1 == self._barrier:
+                        # checkpoint boundary: the prefetch gate drained
+                        # the queue here (no round past the boundary was
+                        # planned), so syncing params + pending records
+                        # captures exactly the synchronous loop's state
+                        # after round t
+                        self._join_late(block=True)
+                        for i in range(len(pending)):
+                            if not isinstance(pending[i], RoundRecord):
+                                pending[i] = srv._finalize(pending[i])
+                        srv.save_state(params, t + 1,
+                                       History(records=prefix + pending))
+                        self._barrier = self._next_barrier(t + 1, T)
+                        self._prefetch(T, self.depth)
+                        if self._queue:
+                            # restart the stream: the boundary round's
+                            # probe runs standalone on the just-saved
+                            # params (same math as the fused/chained
+                            # dispatch — pinned by the engine-parity tests)
+                            sampled = self._queue.popleft()
+                            stats_dev = (client.probe_cohort_raw(
+                                params, sampled.probe_batches, reqs,
+                                score_fn)
+                                if sampled.probe_batches is not None
+                                else None)
+                    elif self._queue:
+                        sampled, stats_dev = self._queue.popleft(), nstats
         finally:
             pool.shutdown(wait=True)
 

@@ -62,6 +62,7 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.strategy import SelectionContext, Strategy, get_strategy
 from repro.configs.base import FLConfig
@@ -355,6 +356,12 @@ class FLServer:
         without hooks — e.g. ``SyntheticFederatedData`` — consume the server
         rng exactly as before, so seeds and parity are unchanged.
         """
+        with TraceAnnotation("fl.plan", round=t) as span:
+            cohort = self._draw_cohort(t)
+            span.set_metadata(cohort=len(cohort))
+            return self._plan_for(cohort, t)
+
+    def _draw_cohort(self, t: int) -> np.ndarray:
         avail = getattr(self.data, "available_clients", None)
         pool = avail(t, self.rng) if callable(avail) else None
         if pool is None:                 # full availability: legacy rng path
@@ -394,9 +401,9 @@ class FLServer:
                         f"round {t}: drop_stragglers marked the entire "
                         f"cohort; running it in full instead (counted in "
                         f"select_stats['all_straggler_rounds']; warning "
-                        f"once per server)", stacklevel=2)
+                        f"once per server)", stacklevel=3)
                     self._straggler_warned = True
-        return self._plan_for(cohort, t)
+        return cohort
 
     # -- stage 2: sample (host; prefetchable) ----------------------------
     def sample_round(self, plan: RoundPlan) -> SampledRound:
@@ -404,17 +411,20 @@ class FLServer:
         batches first, then update batches — the order both engines consume
         them in, and the order the synchronous loop draws them in."""
         fl = self.fl
-        probe_b = (self.data.cohort_batches(plan.probe_ids, fl.batch_size,
-                                            fl.selection_batches)
-                   if len(plan.probe_ids) else None)
-        update_b = self.data.cohort_batches(plan.cohort, fl.batch_size,
-                                            fl.local_steps)
-        # Explicit h2d here, in the (pipelined, overlapped) sample stage:
-        # handing raw np batches to the jitted engines would be an implicit
-        # transfer at dispatch time — REPRO_STRICT's transfer guard rejects
-        # exactly that, and the copy would land in the hot segment.
-        update_b = jax.device_put(update_b)
-        probe_b = jax.device_put(probe_b) if probe_b is not None else None
+        with TraceAnnotation("fl.sample", round=plan.t,
+                             cohort=len(plan.cohort)):
+            probe_b = (self.data.cohort_batches(
+                plan.probe_ids, fl.batch_size, fl.selection_batches)
+                if len(plan.probe_ids) else None)
+            update_b = self.data.cohort_batches(plan.cohort, fl.batch_size,
+                                                fl.local_steps)
+            # Explicit h2d here, in the (pipelined, overlapped) sample
+            # stage: handing raw np batches to the jitted engines would be
+            # an implicit transfer at dispatch time — REPRO_STRICT's
+            # transfer guard rejects exactly that, and the copy would land
+            # in the hot segment.
+            update_b = jax.device_put(update_b)
+            probe_b = jax.device_put(probe_b) if probe_b is not None else None
         return SampledRound(plan=plan, update_batches=update_b,
                             probe_batches=probe_b)
 
@@ -513,7 +523,9 @@ class FLServer:
                                init=self._warm_init(plan.cohort, probe,
                                                     plan.budgets))
         if not self.strategy.host:
-            return self.strategy.select(probe, plan.budgets, ctx)
+            with TraceAnnotation("fl.solve", round=plan.t, memo_hit=False,
+                                 clients=len(plan.cohort)):
+                return self.strategy.select(probe, plan.budgets, ctx)
         if self._faults_active and self._injector.solver_stalls(plan.t):
             # injected solver stall: the (P1) solve missed its deadline —
             # degrade to warm/greedy fallback masks instead of blocking
@@ -523,16 +535,21 @@ class FLServer:
         # round-independent (Strategy.memoizable_select) — a custom host
         # strategy with e.g. an annealing schedule must never be replayed
         memoizable = getattr(self.strategy, "memoizable_select", False)
-        key = self._memo_key(plan, probe, ctx.init) if memoizable else None
-        if memoizable and self._select_memo is not None \
-                and self._select_memo[0] == key:
-            self.select_stats["memo_hits"] += 1
-            masks = self._select_memo[1].copy()
-        else:
-            masks = self.strategy.select(probe, plan.budgets, ctx)
-            self.select_stats["solves"] += 1
-            if memoizable:
-                self._select_memo = (key, masks.copy())
+        with TraceAnnotation("fl.solve", round=plan.t,
+                             clients=len(plan.cohort)) as span:
+            key = (self._memo_key(plan, probe, ctx.init) if memoizable
+                   else None)
+            hit = (memoizable and self._select_memo is not None
+                   and self._select_memo[0] == key)
+            if hit:
+                self.select_stats["memo_hits"] += 1
+                masks = self._select_memo[1].copy()
+            else:
+                masks = self.strategy.select(probe, plan.budgets, ctx)
+                self.select_stats["solves"] += 1
+                if memoizable:
+                    self._select_memo = (key, masks.copy())
+            span.set_metadata(memo_hit=hit)
         self.state.set_warm_rows(plan.cohort, masks, t=plan.t)
         return masks
 
@@ -752,15 +769,17 @@ class FLServer:
         the task exposes ``state_dict``) the task's stream state, as one
         flat-array tree; History and select_stats ride the manifest."""
         from repro.ckpt import save_checkpoint
-        tree = {"params": params,
-                "client": self.state.state_dict(),
-                "server_rng": rng_state_to_arrays(self.rng)}
-        task_sd = getattr(self.data, "state_dict", None)
-        if callable(task_sd):
-            tree["task"] = task_sd()
-        extra = {"round": t_next, "history": history.to_json(),
-                 "select_stats": dict(self.select_stats)}
-        path = save_checkpoint(self.checkpoint_dir, t_next, tree, extra=extra)
+        with TraceAnnotation("fl.checkpoint", round=t_next - 1):
+            tree = {"params": params,
+                    "client": self.state.state_dict(),
+                    "server_rng": rng_state_to_arrays(self.rng)}
+            task_sd = getattr(self.data, "state_dict", None)
+            if callable(task_sd):
+                tree["task"] = task_sd()
+            extra = {"round": t_next, "history": history.to_json(),
+                     "select_stats": dict(self.select_stats)}
+            path = save_checkpoint(self.checkpoint_dir, t_next, tree,
+                                   extra=extra)
         if self._faults_active:          # post-save media damage (DESIGN.md §12)
             self._injector.maybe_corrupt_checkpoint(path, t_next)
         return path
@@ -843,17 +862,21 @@ class FLServer:
 
     # -- streaming pipeline (repro.core.scheduler.RoundScheduler) ---------
     @staticmethod
-    def _stats_np(stats_dev) -> Optional[dict[str, np.ndarray]]:
-        """Materialise a raw probe result (the pipeline's one sync point)."""
+    def _stats_np(stats_dev, t: int) -> Optional[dict[str, np.ndarray]]:
+        """Materialise round ``t``'s raw probe result (the pipeline's one
+        sync point)."""
         if stats_dev is None:
             return None
-        return probe_stats_dict(stats_dev)
+        with TraceAnnotation("fl.stats_sync", round=t,
+                             probed=jax.tree.leaves(stats_dev)[0].shape[0]):
+            return probe_stats_dict(stats_dev)
 
     def _finalize(self, entry: tuple) -> RoundRecord:
         plan, masks, losses, loss_dev, acc_dev, wall_s = entry
-        # repro: allow[host-sync] -- the round boundary: lazy record finalisation is the sanctioned d2h point
-        return self._make_record(plan, masks, float(np.mean(np.asarray(losses))),
-                                 float(loss_dev), float(acc_dev), wall_s)  # repro: allow[host-sync] -- same round-boundary materialisation
+        with TraceAnnotation("fl.finalize", round=plan.t):
+            # repro: allow[host-sync] -- the round boundary: lazy record finalisation is the sanctioned d2h point
+            return self._make_record(plan, masks, float(np.mean(np.asarray(losses))),
+                                     float(loss_dev), float(acc_dev), wall_s)  # repro: allow[host-sync] -- same round-boundary materialisation
 
     @staticmethod
     def _print_round(rec: RoundRecord) -> None:

@@ -180,9 +180,11 @@ def base_delta_matmul(x, w, dw, slots, *, block_f: int = 512,
         x2 = x[:, 0]
     else:
         x2 = x
-    if m == "jnp":
-        out = _dmm.base_delta_matmul_2d_jnp(x2, w, dw, slots)
-    else:
-        out = _dmm.base_delta_matmul_2d(x2, w, dw, slots, block_f=block_f,
-                                        interpret=_auto_interpret(interpret))
+    with jax.named_scope("delta_matmul"):
+        if m == "jnp":
+            out = _dmm.base_delta_matmul_2d_jnp(x2, w, dw, slots)
+        else:
+            out = _dmm.base_delta_matmul_2d(
+                x2, w, dw, slots, block_f=block_f,
+                interpret=_auto_interpret(interpret))
     return out[:, None] if squeeze else out

@@ -16,6 +16,11 @@ Three personalization modes (DESIGN.md §9):
   full-parameter copy (materialised on refill), decode is vmapped over
   the stacked per-slot params.
 
+Each pass of the loop is a ``serve.step`` profiler span (its counters:
+occupied slots, slots fed a prompt token whose output is discarded, queued
+requests) holding ``serve.admit``, ``serve.feed``, ``serve.dispatch``,
+``serve.sync`` and ``serve.bookkeep``; PERF.md §3 lists what reads each.
+
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --slots 4 --requests 10 --max-new 16 --mode delta --delta-layers 2
 """
@@ -29,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import RuntimeConfig, get_arch, reduced
 from repro.launch.compile_cache import use_compile_cache
@@ -116,7 +122,9 @@ class SlotServer:
         self._fail_counts.pop(req.rid, None)
         print(f"  dropping request {req.rid} (user {req.user_id}): {why}")
 
-    def _admit(self, queue: list[Request]):
+    def _admit(self, queue: list[Request]) -> int:
+        """Fill free slots from the head of ``queue``; the number admitted."""
+        admitted = 0
         for i in range(self.slots):
             if self.active[i] is not None or not queue:
                 continue
@@ -155,6 +163,8 @@ class SlotServer:
                     self.cache, jnp.int32(i))
             self.active[i] = req
             self.pos[i] = 0
+            admitted += 1
+        return admitted
 
     def _decode(self, toks, pos):
         if self.mode == "shared":
@@ -167,64 +177,87 @@ class SlotServer:
         return self.suite["serve_decode_dense"](self.bank, toks, pos,
                                                 self.cache, self.window)
 
+    def _bookkeep(self, nxt: np.ndarray, steps: int, queue: list[Request],
+                  done: list[Request], verbose: bool) -> None:
+        """After decode step ``steps``: append each slot's token, free the
+        finished slots, strike the injected slot faults."""
+        for i, r in enumerate(self.active):
+            if r is None:
+                continue
+            self.pos[i] += 1
+            if self.pos[i] >= len(r.prompt):
+                r.generated.append(int(nxt[i]))  # repro: allow[host-sync] -- nxt already materialised at the sanctioned sync above
+            if r.done or self.pos[i] >= self.max_seq - 1:
+                done.append(r)
+                self._free(i)
+        if self.injector is not None and self.injector.enabled:
+            # injected slot failures (DESIGN.md §12): the struck slot's
+            # request loses its progress; bounded per-request retries
+            # from scratch (generated cleared — admit resets pos/cache),
+            # then dropped
+            struck = self.injector.slot_faults(steps, self.slots)
+            for i in np.flatnonzero(struck):
+                r = self.active[i]
+                if r is None:
+                    continue
+                self._slot_failures += 1
+                self._free(int(i))  # repro: allow[host-sync] -- i is a host np index from the injector's host draw
+                n = self._fail_counts.get(r.rid, 0) + 1
+                self._fail_counts[r.rid] = n
+                if n > self.max_slot_retries:
+                    self._drop(r, f"slot failed {n} times")
+                else:
+                    r.generated.clear()
+                    queue.append(r)
+        if verbose and steps % 8 == 0:
+            print(f"  step {steps}: {sum(x is not None for x in self.active)}"
+                  f" active, {len(queue)} queued, {len(done)} done")
+
     def run(self, requests: list[Request], verbose: bool = False):
         queue = list(requests)
         done: list[Request] = []
         steps = 0
         t0 = time.time()  # repro: allow[nondeterminism] -- serve wall-clock telemetry only
         while queue or any(r is not None for r in self.active):
-            self._admit(queue)
-            if queue and all(r is None for r in self.active):
-                # nothing admitted onto an idle server: skip the decode —
-                # admit attempts ramp every pass, so the stuck head is
-                # dropped within admit_retries iterations (no livelock,
-                # no RuntimeError: the batch degrades instead of dying)
-                continue
-            toks = np.zeros(self.slots, np.int32)
-            for i, r in enumerate(self.active):
-                if r is None:
+            # every span of one pass carries the id of the decode step it
+            # runs (``steps`` before the increment)
+            with StepTraceAnnotation("serve.step", step_num=steps) as span:
+                with TraceAnnotation("serve.admit", step=steps) as admit:
+                    admit.set_metadata(admitted=self._admit(queue))
+                if queue and all(r is None for r in self.active):
+                    # nothing admitted onto an idle server: skip the decode
+                    # — admit attempts ramp every pass, so the stuck head
+                    # is dropped within admit_retries iterations (no
+                    # livelock, no RuntimeError: the batch degrades
+                    # instead of dying)
                     continue
-                p = int(self.pos[i])  # repro: allow[host-sync] -- self.pos is the host np position mirror, no device value
-                toks[i] = (r.prompt[p] if p < len(r.prompt)
-                           else r.generated[-1])
-            # per-slot position vector: each slot decodes at its own stream
-            # position; empty slots idle at 0 and are masked on refill
-            logits, self.cache = self._decode(jnp.asarray(toks),
-                                              jnp.asarray(self.pos))
-            # repro: allow[host-sync] -- the serve loop's one sanctioned sync: greedy feedback, next token depends on this step's logits
-            nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-            steps += 1
-            for i, r in enumerate(self.active):
-                if r is None:
-                    continue
-                self.pos[i] += 1
-                if self.pos[i] >= len(r.prompt):
-                    r.generated.append(int(nxt[i]))  # repro: allow[host-sync] -- nxt already materialised at the sanctioned sync above
-                if r.done or self.pos[i] >= self.max_seq - 1:
-                    done.append(r)
-                    self._free(i)
-            if self.injector is not None and self.injector.enabled:
-                # injected slot failures (DESIGN.md §12): the struck slot's
-                # request loses its progress; bounded per-request retries
-                # from scratch (generated cleared — admit resets pos/cache),
-                # then dropped
-                struck = self.injector.slot_faults(steps, self.slots)
-                for i in np.flatnonzero(struck):
-                    r = self.active[i]
-                    if r is None:
-                        continue
-                    self._slot_failures += 1
-                    self._free(int(i))  # repro: allow[host-sync] -- i is a host np index from the injector's host draw
-                    n = self._fail_counts.get(r.rid, 0) + 1
-                    self._fail_counts[r.rid] = n
-                    if n > self.max_slot_retries:
-                        self._drop(r, f"slot failed {n} times")
-                    else:
-                        r.generated.clear()
-                        queue.append(r)
-            if verbose and steps % 8 == 0:
-                print(f"  step {steps}: {sum(x is not None for x in self.active)}"
-                      f" active, {len(queue)} queued, {len(done)} done")
+                with TraceAnnotation("serve.feed", step=steps):
+                    toks = np.zeros(self.slots, np.int32)
+                    active = prompt = 0
+                    for i, r in enumerate(self.active):
+                        if r is None:
+                            continue
+                        p = int(self.pos[i])  # repro: allow[host-sync] -- self.pos is the host np position mirror, no device value
+                        toks[i] = (r.prompt[p] if p < len(r.prompt)
+                                   else r.generated[-1])
+                        active += 1
+                        # a prompt token but the last: its output is
+                        # discarded
+                        prompt += p < len(r.prompt) - 1
+                span.set_metadata(active=active, prompt=prompt,
+                                  queued=len(queue))
+                # per-slot position vector: each slot decodes at its own
+                # stream position; empty slots idle at 0 and are masked on
+                # refill
+                with TraceAnnotation("serve.dispatch", step=steps):
+                    logits, self.cache = self._decode(jnp.asarray(toks),
+                                                      jnp.asarray(self.pos))
+                with TraceAnnotation("serve.sync", step=steps):
+                    # repro: allow[host-sync] -- the serve loop's one sanctioned sync: greedy feedback, next token depends on this step's logits
+                    nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+                with TraceAnnotation("serve.bookkeep", step=steps):
+                    steps += 1
+                    self._bookkeep(nxt, steps, queue, done, verbose)
         dt = time.time() - t0  # repro: allow[nondeterminism] -- serve wall-clock telemetry only
         gen = sum(len(r.generated) for r in done)
         return done, {"steps": steps, "wall_s": dt, "gen_tokens": gen,

@@ -775,7 +775,7 @@ class Model:
 
         Returns (logits (B,V), new_cache).
         """
-        cfg, rt = self.cfg, self.runtime
+        cfg = self.cfg
         per_slot = jnp.ndim(pos) == 1
         if delta is not None and not supports_delta_decode(cfg):
             raise ValueError(f"family {cfg.family!r} has no delta-decode path")
@@ -787,7 +787,16 @@ class Model:
             x = params["embed"]["tok"][tokens[:, None]] + sp.astype(x.dtype)
         positions = (pos[:, None] if per_slot else pos[None]).astype(jnp.int32)
         w = window or cfg.sliding_window
+        with jax.named_scope("decode_blocks"):
+            x, new_cache = self._decode_blocks(params, x, positions, pos,
+                                               cache, w, delta)
+        with jax.named_scope("lm_head"):
+            logits = self._head(params, x)[:, 0]
+        return logits, new_cache
 
+    def _decode_blocks(self, params, x, positions, pos, cache, w, delta):
+        """The layer stack of :meth:`decode_step`, one family's scan."""
+        cfg, rt = self.cfg, self.runtime
         if cfg.family in ("dense", "vlm"):
             def step(carry, inp):
                 p, kv = inp[:2]
@@ -860,9 +869,7 @@ class Model:
             new_cache = {"blocks": nkv, "cross_kv": cache["cross_kv"]}
         else:
             raise ValueError(cfg.family)
-
-        logits = self._head(params, x)[:, 0]
-        return logits, new_cache
+        return x, new_cache
 
     def _zamba_decode(self, params, x, positions, pos, cache, w):
         cfg = self.cfg

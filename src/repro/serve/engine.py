@@ -22,6 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.client import _JIT_CACHE, _JIT_STATS
 from repro.models.model import Model, _block_shapes, supports_delta_decode
@@ -136,10 +137,11 @@ class DeltaOverlay:
                     return False
                 self.stats["upload_retries"] += 1
                 continue
-            rows = {name: jnp.asarray(leaves[name][j])
-                    for name in self.leaves}
-            self.leaves = self._write(self.leaves, jnp.int32(li),
-                                      jnp.int32(c), rows)
+            with TraceAnnotation("serve.upload", layer=li):
+                rows = {name: jnp.asarray(leaves[name][j])
+                        for name in self.leaves}
+                self.leaves = self._write(self.leaves, jnp.int32(li),
+                                          jnp.int32(c), rows)
             return True
 
     def release(self, slot: int) -> None:
