@@ -17,8 +17,8 @@ time split by program phase.
   from an ``--xla_dump_to`` directory); an operation is found there by its
   module (the ``XLA Modules`` event around it) and its instruction, and a
   fusion takes the scope of its root, whose metadata XLA keeps.
-* :func:`idle_by_span` and :func:`device_by_scope` reduce a
-  :class:`SpanTrace`; :func:`probe_share`, :func:`solve_share`,
+* :func:`idle_by_span`, :func:`device_by_scope` and :func:`counters`
+  reduce a :class:`SpanTrace`; :func:`probe_share`, :func:`solve_share`,
   :func:`host_loop_share` and :func:`prompt_step_share` are the per-layer
   shares.  The two that need only span names and times
   (:func:`solve_share`, :func:`host_loop_share`) also read the host events
@@ -27,8 +27,8 @@ time split by program phase.
 
 Run as a script it measures one cell with the profiler on, as
 ``bench/run.py --trace 1`` does, and prints that run's result line with
-``idle_by_span``, ``device_by_scope`` and the four shares added, and the
-window's end-to-end numbers beside them (``traced``)::
+``idle_by_span``, ``device_by_scope``, ``counters`` and the four shares
+added, and the window's end-to-end numbers beside them (``traced``)::
 
     python3 bench/spans.py --workload <cell> --seed <n> [--seconds 10]
 
@@ -55,6 +55,7 @@ SCOPES = ("probe", "local_steps", "aggregate", "eval",
           "decode_blocks", "delta_matmul", "lm_head")
 PREFIXES = ("fl.", "serve.")
 LOOP_ROOTS = ("fl.round", "serve.step")
+IDS = ("round", "step", "step_num")
 NO_SPAN = "(no program span)"
 NO_SCOPE = "(no scope)"
 MODULE_LINE = "XLA Modules"
@@ -77,7 +78,7 @@ class Span:
     @property
     def id(self) -> Optional[int]:
         """The round or step the span belongs to."""
-        for k in ("round", "step", "step_num"):
+        for k in IDS:
             if k in self.stats:
                 return self.stats[k]
         return None
@@ -88,7 +89,7 @@ class SpanTrace:
     window: tuple[float, float]
     spans: list[Span]
     ops: list[T.Event]            # the busiest device's, clipped to the window
-    scopes: list[Optional[str]]   # each op's top-level scope, or None
+    scopes: list[Optional[str]]   # each op's scope path, or None
 
     @property
     def window_s(self) -> float:
@@ -108,19 +109,25 @@ _WRAPPED = re.compile(r"^[\w.-]*\((.*)\)$")
 
 
 def scope_of(op_name: str) -> Optional[str]:
-    """The outermost of :data:`SCOPES` in an ``op_name`` path
-    (``jit(f)/jit(f)/vmap(local_steps)/while/body/dot_general`` →
-    ``local_steps``); transformations wrap a scope's name
-    (``vmap(...)``, ``transpose(jvp(...))``)."""
+    """The :data:`SCOPES` in an ``op_name`` path, outermost first and
+    joined by ``/`` (``jit(f)/jit(f)/vmap(local_steps)/while/body/dot`` →
+    ``local_steps``; ``jit(g)/decode_blocks/while/body/delta_matmul/pad``
+    → ``decode_blocks/delta_matmul``); transformations wrap a scope's name
+    (``vmap(...)``, ``transpose(jvp(...))``), and a name inside itself is
+    one scope (``probe/vmap(vmap(probe))``: the scope, then the function
+    of that name)."""
+    found: list[str] = []
     for part in op_name.split("/"):
         while True:
             if part in SCOPES:
-                return part
+                if part not in found:
+                    found.append(part)
+                break
             m = _WRAPPED.match(part)
             if not m:
                 break
             part = m.group(1)
-    return None
+    return "/".join(found) or None
 
 
 _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
@@ -306,7 +313,9 @@ def idle_by_span(tr: SpanTrace, gaps=None) -> dict[str, float]:
 
 def device_by_scope(tr: SpanTrace) -> dict[str, float]:
     """Device seconds of the innermost operations in the window by their
-    top-level scope; the rest under :data:`NO_SCOPE`."""
+    scope path: a top-level scope (``decode_blocks``), or a scope nested
+    in one (``decode_blocks/delta_matmul``, which the top-level key then
+    leaves out); the rest under :data:`NO_SCOPE`."""
     scope = {id(e): s for e, s in zip(tr.ops, tr.scopes)}
     out: dict[str, float] = {}
     for e in T.leaves(tr.ops):
@@ -328,7 +337,8 @@ def probe_share(tr: SpanTrace) -> Optional[float]:
     total = sum(by.values())
     if total <= 0 or set(by) <= {NO_SCOPE}:
         return None
-    return 100.0 * by.get("probe", 0.0) / total
+    return 100.0 * sum(v for k, v in by.items()
+                       if k.split("/")[0] == "probe") / total
 
 
 def solve_share(events, window) -> Optional[float]:
@@ -364,6 +374,24 @@ def prompt_step_share(tr: SpanTrace) -> Optional[float]:
     if not active:
         return None
     return 100.0 * sum(s["prompt"] for s in steps) / active
+
+
+def counters(tr: SpanTrace) -> dict[str, dict[str, float]]:
+    """For each span name with counters, over its spans that began in the
+    window: their number ``n`` and each counter's mean (``serve.step``'s
+    ``queued``: the mean queue; ``fl.solve``'s ``memo_hit``: the share of
+    solves replayed).  The ids (``round``, ``step``) are left out."""
+    t0, t1 = tr.window
+    out: dict[str, dict[str, float]] = {}
+    for name in sorted({s.name for s in tr.spans}):
+        runs = [s.stats for s in tr.spans
+                if s.name == name and t0 <= s.start < t1]
+        keys = sorted({k for st in runs for k in st} - set(IDS))
+        if runs and keys:
+            out[name] = {"n": len(runs), **{
+                k: sum(st.get(k, 0) for st in runs) / len(runs)
+                for k in keys}}
+    return out
 
 
 def shares(tr: SpanTrace) -> dict[str, Optional[float]]:
@@ -451,7 +479,8 @@ def main(argv=None) -> None:
                                  long_idle_by_span=idle_by_span(st, [
                                      g for g in idle_gaps(st)
                                      if g[1] - g[0] >= 1e-3]),
-                                 device_by_scope=device_by_scope(st)),
+                                 device_by_scope=device_by_scope(st),
+                                 counters=counters(st)),
                "spans": {n: sum(1 for s in st.spans if s.name == n)
                          for n in sorted({s.name for s in st.spans})}}
         print(json.dumps(out, default=float), flush=True)

@@ -120,6 +120,7 @@ def test_nothing_to_read_without_spans():
          "serve.prompt_step_share"))
     assert S.device_by_scope(tr) == {S.NO_SCOPE: 0.5}
     assert S.idle_by_span(tr) == {S.NO_SPAN: pytest.approx(0.5)}
+    assert S.counters(tr) == {}
 
 
 def _span(name, a, b, line=0, **stats):
@@ -164,11 +165,39 @@ def test_device_by_scope_and_probe_share():
     assert S.probe_share(tr) == pytest.approx(100 * 1.0 / 5.0)
 
 
+def test_device_by_scope_keeps_a_nested_scope_apart():
+    """The decode overlay's pad and kernel (``delta_matmul``, inside the
+    layer scan) are kept apart from the rest of ``decode_blocks``; the
+    probe's share counts every path under ``probe``."""
+    ops = [T.Event("pad", 0.0, 2.0), T.Event("kernel", 2.0, 3.0),
+           T.Event("dot", 3.0, 7.0), T.Event("head", 7.0, 8.0),
+           T.Event("grad_norm", 8.0, 9.0)]
+    tr = S.SpanTrace(window=(0.0, 10.0), spans=[], ops=ops,
+                     scopes=["decode_blocks/delta_matmul",
+                             "decode_blocks/delta_matmul", "decode_blocks",
+                             "lm_head", "probe/delta_matmul"])
+    assert S.device_by_scope(tr) == {
+        "decode_blocks": 4.0, "decode_blocks/delta_matmul": 3.0,
+        "lm_head": 1.0, "probe/delta_matmul": 1.0}
+    assert S.probe_share(tr) == pytest.approx(100 * 1.0 / 9.0)
+
+
+def test_counters_average_each_counter(recorded):
+    tr, _ = recorded
+    assert S.counters(tr) == {
+        "fl.dispatch": {"n": 1, "cut": 2, "fused": 1, "probed": 8},
+        "fl.solve": {"n": 1, "clients": 8, "memo_hit": 0},
+        "serve.step": {"n": 2, "active": 8, "prompt": 4, "queued": 3}}
+
+
 @pytest.mark.parametrize("op_name,scope", [
     ("jit(f)/jit(f)/vmap(local_steps)/while/body/dot_general", "local_steps"),
     ("jit(f)/jit(f)/probe/vmap(vmap(transpose(jvp())))/mul", "probe"),
-    ("jit(g)/decode_blocks/delta_matmul/jit(base_delta_matmul_2d)/pad",
-     "decode_blocks"),
+    ("jit(f)/probe/vmap(vmap(probe))/transpose(jvp())/while/body/mul",
+     "probe"),
+    ("jit(g)/decode_blocks/while/body/delta_matmul/jit(base_delta_matmul_2d)"
+     "/pad", "decode_blocks/delta_matmul"),
+    ("jit(g)/jit(g)/decode_blocks/while/body/dot_general", "decode_blocks"),
     ("jit(g)/jit(g)/lm_head/dot_general", "lm_head"),
     ("jit(f)/jit(f)/probes/add", None),
     ("", None),
@@ -262,7 +291,7 @@ HLO_G = ['''HloModule jit_g, entry_computation_layout={()->f32[2]}
 
 ENTRY %main (x: f32[2], y: f32[2]) -> f32[2] {
   %add.3 = f32[2] add(f32[2] %x, f32[2] %y), metadata={op_name="jit(g)/lm_head/add"}
-  ROOT %mul.2 = f32[4] multiply(f32[4] %a, f32[4] %b), metadata={op_name="jit(g)/decode_blocks/mul"}
+  ROOT %mul.2 = f32[4] multiply(f32[4] %a, f32[4] %b), metadata={op_name="jit(g)/decode_blocks/while/body/delta_matmul/mul"}
 }
 ''', '''HloModule jit_g, entry_computation_layout={()->f32[2]}
 
@@ -291,10 +320,11 @@ def test_device_ops_take_their_scope(device_trace):
     assert tr.window == pytest.approx((0.0, 0.02))
     assert [e.op for e in tr.ops] == ["fusion.1", "add.3", "copy.7",
                                       "add.3", "mul.2"]
-    assert tr.scopes == ["probe", "aggregate", None, None, "decode_blocks"]
+    assert tr.scopes == ["probe", "aggregate", None, None,
+                         "decode_blocks/delta_matmul"]
     assert S.device_by_scope(tr) == pytest.approx(
-        {"probe": 0.004, "aggregate": 0.003, "decode_blocks": 0.002,
-         S.NO_SCOPE: 0.003})
+        {"probe": 0.004, "aggregate": 0.003,
+         "decode_blocks/delta_matmul": 0.002, S.NO_SCOPE: 0.003})
     assert [(s.name, s.id) for s in tr.spans] == [("serve.step", 4),
                                                   ("serve.sync", 4)]
     # the device idles 0-1 ms, 5-6 ms, 10-14 ms and 18-20 ms; the loop

@@ -357,53 +357,49 @@ class FLServer:
         rng exactly as before, so seeds and parity are unchanged.
         """
         with TraceAnnotation("fl.plan", round=t) as span:
-            cohort = self._draw_cohort(t)
+            avail = getattr(self.data, "available_clients", None)
+            pool = avail(t, self.rng) if callable(avail) else None
+            if pool is None:                 # full availability: legacy rng path
+                cohort = self.rng.choice(self.fl.n_clients,
+                                         size=self.fl.cohort_size, replace=False)
+            else:
+                pool = np.asarray(pool)
+                if pool.size == 0:
+                    # an empty cohort would reach aggregation/np.mean(losses)
+                    # and crash with an opaque error several stages later —
+                    # fail at the plan stage with the actual cause instead
+                    raise ValueError(
+                        f"available_clients returned an empty pool for round "
+                        f"{t}: no cohort can be drawn (the task's availability "
+                        f"hook must return at least one client id, or None for "
+                        f"full availability)")
+                k = min(self.fl.cohort_size, len(pool))
+                cohort = pool[self.rng.choice(len(pool), size=k, replace=False)]
+            drop = getattr(self.data, "drop_stragglers", None)
+            if callable(drop):
+                keep = np.asarray(drop(t, cohort, self.rng), bool)
+                if keep.shape != cohort.shape:
+                    raise ValueError(
+                        f"drop_stragglers returned keep-mask of shape "
+                        f"{keep.shape} for a round-{t} cohort of shape "
+                        f"{cohort.shape}")
+                if keep.any():               # never drop the whole cohort
+                    cohort = cohort[keep]
+                else:
+                    # every member straggled: the round runs on the full cohort
+                    # (an empty round would crash downstream), but no longer
+                    # silently — a run dominated by these is not degrading the
+                    # way its straggler model says it should
+                    self.select_stats["all_straggler_rounds"] += 1
+                    if not self._straggler_warned:
+                        warnings.warn(
+                            f"round {t}: drop_stragglers marked the entire "
+                            f"cohort; running it in full instead (counted in "
+                            f"select_stats['all_straggler_rounds']; warning "
+                            f"once per server)", stacklevel=2)
+                        self._straggler_warned = True
             span.set_metadata(cohort=len(cohort))
             return self._plan_for(cohort, t)
-
-    def _draw_cohort(self, t: int) -> np.ndarray:
-        avail = getattr(self.data, "available_clients", None)
-        pool = avail(t, self.rng) if callable(avail) else None
-        if pool is None:                 # full availability: legacy rng path
-            cohort = self.rng.choice(self.fl.n_clients,
-                                     size=self.fl.cohort_size, replace=False)
-        else:
-            pool = np.asarray(pool)
-            if pool.size == 0:
-                # an empty cohort would reach aggregation/np.mean(losses)
-                # and crash with an opaque error several stages later —
-                # fail at the plan stage with the actual cause instead
-                raise ValueError(
-                    f"available_clients returned an empty pool for round "
-                    f"{t}: no cohort can be drawn (the task's availability "
-                    f"hook must return at least one client id, or None for "
-                    f"full availability)")
-            k = min(self.fl.cohort_size, len(pool))
-            cohort = pool[self.rng.choice(len(pool), size=k, replace=False)]
-        drop = getattr(self.data, "drop_stragglers", None)
-        if callable(drop):
-            keep = np.asarray(drop(t, cohort, self.rng), bool)
-            if keep.shape != cohort.shape:
-                raise ValueError(
-                    f"drop_stragglers returned keep-mask of shape "
-                    f"{keep.shape} for a round-{t} cohort of shape "
-                    f"{cohort.shape}")
-            if keep.any():               # never drop the whole cohort
-                cohort = cohort[keep]
-            else:
-                # every member straggled: the round runs on the full cohort
-                # (an empty round would crash downstream), but no longer
-                # silently — a run dominated by these is not degrading the
-                # way its straggler model says it should
-                self.select_stats["all_straggler_rounds"] += 1
-                if not self._straggler_warned:
-                    warnings.warn(
-                        f"round {t}: drop_stragglers marked the entire "
-                        f"cohort; running it in full instead (counted in "
-                        f"select_stats['all_straggler_rounds']; warning "
-                        f"once per server)", stacklevel=3)
-                    self._straggler_warned = True
-        return cohort
 
     # -- stage 2: sample (host; prefetchable) ----------------------------
     def sample_round(self, plan: RoundPlan) -> SampledRound:

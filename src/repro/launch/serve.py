@@ -122,8 +122,9 @@ class SlotServer:
         self._fail_counts.pop(req.rid, None)
         print(f"  dropping request {req.rid} (user {req.user_id}): {why}")
 
-    def _admit(self, queue: list[Request]) -> int:
-        """Fill free slots from the head of ``queue``; the number admitted."""
+    def _admit(self, queue: list[Request], step: int = -1) -> int:
+        """Fill free slots from the head of ``queue`` at serve step
+        ``step``; the number admitted."""
         admitted = 0
         for i in range(self.slots):
             if self.active[i] is not None or not queue:
@@ -132,7 +133,8 @@ class SlotServer:
                 req = None
                 while queue:
                     head = queue[0]
-                    if self.overlay.try_admit(i, self._record(head)):
+                    if self.overlay.try_admit(i, self._record(head),
+                                              step=step):
                         req = queue.pop(0)
                         break
                     # overlay full for this request: bounded retry, then
@@ -177,42 +179,6 @@ class SlotServer:
         return self.suite["serve_decode_dense"](self.bank, toks, pos,
                                                 self.cache, self.window)
 
-    def _bookkeep(self, nxt: np.ndarray, steps: int, queue: list[Request],
-                  done: list[Request], verbose: bool) -> None:
-        """After decode step ``steps``: append each slot's token, free the
-        finished slots, strike the injected slot faults."""
-        for i, r in enumerate(self.active):
-            if r is None:
-                continue
-            self.pos[i] += 1
-            if self.pos[i] >= len(r.prompt):
-                r.generated.append(int(nxt[i]))  # repro: allow[host-sync] -- nxt already materialised at the sanctioned sync above
-            if r.done or self.pos[i] >= self.max_seq - 1:
-                done.append(r)
-                self._free(i)
-        if self.injector is not None and self.injector.enabled:
-            # injected slot failures (DESIGN.md §12): the struck slot's
-            # request loses its progress; bounded per-request retries
-            # from scratch (generated cleared — admit resets pos/cache),
-            # then dropped
-            struck = self.injector.slot_faults(steps, self.slots)
-            for i in np.flatnonzero(struck):
-                r = self.active[i]
-                if r is None:
-                    continue
-                self._slot_failures += 1
-                self._free(int(i))  # repro: allow[host-sync] -- i is a host np index from the injector's host draw
-                n = self._fail_counts.get(r.rid, 0) + 1
-                self._fail_counts[r.rid] = n
-                if n > self.max_slot_retries:
-                    self._drop(r, f"slot failed {n} times")
-                else:
-                    r.generated.clear()
-                    queue.append(r)
-        if verbose and steps % 8 == 0:
-            print(f"  step {steps}: {sum(x is not None for x in self.active)}"
-                  f" active, {len(queue)} queued, {len(done)} done")
-
     def run(self, requests: list[Request], verbose: bool = False):
         queue = list(requests)
         done: list[Request] = []
@@ -223,7 +189,7 @@ class SlotServer:
             # runs (``steps`` before the increment)
             with StepTraceAnnotation("serve.step", step_num=steps) as span:
                 with TraceAnnotation("serve.admit", step=steps) as admit:
-                    admit.set_metadata(admitted=self._admit(queue))
+                    admit.set_metadata(admitted=self._admit(queue, steps))
                 if queue and all(r is None for r in self.active):
                     # nothing admitted onto an idle server: skip the decode
                     # — admit attempts ramp every pass, so the stuck head
@@ -257,7 +223,37 @@ class SlotServer:
                     nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
                 with TraceAnnotation("serve.bookkeep", step=steps):
                     steps += 1
-                    self._bookkeep(nxt, steps, queue, done, verbose)
+                    for i, r in enumerate(self.active):
+                        if r is None:
+                            continue
+                        self.pos[i] += 1
+                        if self.pos[i] >= len(r.prompt):
+                            r.generated.append(int(nxt[i]))  # repro: allow[host-sync] -- nxt already materialised at the sanctioned sync above
+                        if r.done or self.pos[i] >= self.max_seq - 1:
+                            done.append(r)
+                            self._free(i)
+                    if self.injector is not None and self.injector.enabled:
+                        # injected slot failures (DESIGN.md §12): the struck slot's
+                        # request loses its progress; bounded per-request retries
+                        # from scratch (generated cleared — admit resets pos/cache),
+                        # then dropped
+                        struck = self.injector.slot_faults(steps, self.slots)
+                        for i in np.flatnonzero(struck):
+                            r = self.active[i]
+                            if r is None:
+                                continue
+                            self._slot_failures += 1
+                            self._free(int(i))  # repro: allow[host-sync] -- i is a host np index from the injector's host draw
+                            n = self._fail_counts.get(r.rid, 0) + 1
+                            self._fail_counts[r.rid] = n
+                            if n > self.max_slot_retries:
+                                self._drop(r, f"slot failed {n} times")
+                            else:
+                                r.generated.clear()
+                                queue.append(r)
+                    if verbose and steps % 8 == 0:
+                        print(f"  step {steps}: {sum(x is not None for x in self.active)}"
+                              f" active, {len(queue)} queued, {len(done)} done")
         dt = time.time() - t0  # repro: allow[nondeterminism] -- serve wall-clock telemetry only
         gen = sum(len(r.generated) for r in done)
         return done, {"steps": steps, "wall_s": dt, "gen_tokens": gen,

@@ -775,7 +775,7 @@ class Model:
 
         Returns (logits (B,V), new_cache).
         """
-        cfg = self.cfg
+        cfg, rt = self.cfg, self.runtime
         per_slot = jnp.ndim(pos) == 1
         if delta is not None and not supports_delta_decode(cfg):
             raise ValueError(f"family {cfg.family!r} has no delta-decode path")
@@ -788,88 +788,82 @@ class Model:
         positions = (pos[:, None] if per_slot else pos[None]).astype(jnp.int32)
         w = window or cfg.sliding_window
         with jax.named_scope("decode_blocks"):
-            x, new_cache = self._decode_blocks(params, x, positions, pos,
-                                               cache, w, delta)
+            if cfg.family in ("dense", "vlm"):
+                def step(carry, inp):
+                    p, kv = inp[:2]
+                    dl = (inp[2], inp[3]) if delta is not None else None
+                    h, new_kv = _dense_block_fwd(p, carry, cfg, positions=positions,
+                                                 causal=True, window=w, prefix_len=0,
+                                                 seq_chunk=rt.seq_chunk, cache=kv,
+                                                 cache_pos=pos, delta=dl)
+                    return h, new_kv
+                xs = (params["blocks"], cache["blocks"])
+                if delta is not None:
+                    xs = xs + (delta["slots"], delta["leaves"])
+                x, new_kv = lax.scan(step, x, xs)
+                new_cache = {"blocks": new_kv}
+
+            elif cfg.family == "moe":
+                new_cache = {}
+                if cfg.first_dense:
+                    def step0(carry, inp):
+                        p, kv = inp
+                        if cfg.use_mla:
+                            ao, nkv = MLA.mla_fwd(_take(p, "attn_"), carry, cfg,
+                                                  positions=positions, cache=kv,
+                                                  cache_pos=pos, window=w,
+                                                  seq_chunk=rt.seq_chunk)
+                        else:
+                            ao, nkv = B.attention_fwd(_take(p, "attn_"), carry, cfg,
+                                                      positions=positions, cache=kv,
+                                                      cache_pos=pos, causal=True,
+                                                      window=w, seq_chunk=rt.seq_chunk)
+                        h = carry + ao
+                        h = h + B.mlp_fwd(_take(p, "mlp_"), h, cfg)
+                        return h, nkv
+                    x, nkv0 = lax.scan(step0, x, (params["dense0"], cache["dense0"]))
+                    new_cache["dense0"] = nkv0
+
+                def step(carry, inp):
+                    p, kv = inp
+                    h, nkv, _ = _moe_block_fwd(p, carry, cfg, positions=positions,
+                                               window=w, seq_chunk=rt.seq_chunk,
+                                               cache=kv, cache_pos=pos,
+                                               shard=self.shard,
+                                               moe_local=rt.moe_local_dispatch)
+                    return h, nkv
+                x, nkv = lax.scan(step, x, (params["blocks"], cache["blocks"]))
+                new_cache["blocks"] = nkv
+
+            elif cfg.family == "ssm":
+                def step(carry, inp):
+                    p, c = inp
+                    out, nc = SSD.mamba2_fwd(_take(p, "ssm_"), carry, cfg, cache=c)
+                    return carry + out, nc
+                x, nc = lax.scan(step, x, (params["blocks"], cache["blocks"]))
+                new_cache = {"blocks": nc}
+
+            elif cfg.family == "hybrid":
+                x, new_cache = self._zamba_decode(params, x, positions, pos, cache, w)
+
+            elif cfg.family == "audio":
+                def step(carry, inp):
+                    p, kv, xkv = inp
+                    h, nkv = _dense_block_fwd(p, carry, cfg, positions=positions,
+                                              causal=True, window=w, prefix_len=0,
+                                              seq_chunk=rt.seq_chunk, cache=kv,
+                                              cache_pos=pos,
+                                              cross_kv=(xkv["k"], xkv["v"]))
+                    return h, nkv
+                x, nkv = lax.scan(step, x, (params["blocks"], cache["blocks"],
+                                            cache["cross_kv"]))
+                new_cache = {"blocks": nkv, "cross_kv": cache["cross_kv"]}
+            else:
+                raise ValueError(cfg.family)
+
         with jax.named_scope("lm_head"):
             logits = self._head(params, x)[:, 0]
         return logits, new_cache
-
-    def _decode_blocks(self, params, x, positions, pos, cache, w, delta):
-        """The layer stack of :meth:`decode_step`, one family's scan."""
-        cfg, rt = self.cfg, self.runtime
-        if cfg.family in ("dense", "vlm"):
-            def step(carry, inp):
-                p, kv = inp[:2]
-                dl = (inp[2], inp[3]) if delta is not None else None
-                h, new_kv = _dense_block_fwd(p, carry, cfg, positions=positions,
-                                             causal=True, window=w, prefix_len=0,
-                                             seq_chunk=rt.seq_chunk, cache=kv,
-                                             cache_pos=pos, delta=dl)
-                return h, new_kv
-            xs = (params["blocks"], cache["blocks"])
-            if delta is not None:
-                xs = xs + (delta["slots"], delta["leaves"])
-            x, new_kv = lax.scan(step, x, xs)
-            new_cache = {"blocks": new_kv}
-
-        elif cfg.family == "moe":
-            new_cache = {}
-            if cfg.first_dense:
-                def step0(carry, inp):
-                    p, kv = inp
-                    if cfg.use_mla:
-                        ao, nkv = MLA.mla_fwd(_take(p, "attn_"), carry, cfg,
-                                              positions=positions, cache=kv,
-                                              cache_pos=pos, window=w,
-                                              seq_chunk=rt.seq_chunk)
-                    else:
-                        ao, nkv = B.attention_fwd(_take(p, "attn_"), carry, cfg,
-                                                  positions=positions, cache=kv,
-                                                  cache_pos=pos, causal=True,
-                                                  window=w, seq_chunk=rt.seq_chunk)
-                    h = carry + ao
-                    h = h + B.mlp_fwd(_take(p, "mlp_"), h, cfg)
-                    return h, nkv
-                x, nkv0 = lax.scan(step0, x, (params["dense0"], cache["dense0"]))
-                new_cache["dense0"] = nkv0
-
-            def step(carry, inp):
-                p, kv = inp
-                h, nkv, _ = _moe_block_fwd(p, carry, cfg, positions=positions,
-                                           window=w, seq_chunk=rt.seq_chunk,
-                                           cache=kv, cache_pos=pos,
-                                           shard=self.shard,
-                                           moe_local=rt.moe_local_dispatch)
-                return h, nkv
-            x, nkv = lax.scan(step, x, (params["blocks"], cache["blocks"]))
-            new_cache["blocks"] = nkv
-
-        elif cfg.family == "ssm":
-            def step(carry, inp):
-                p, c = inp
-                out, nc = SSD.mamba2_fwd(_take(p, "ssm_"), carry, cfg, cache=c)
-                return carry + out, nc
-            x, nc = lax.scan(step, x, (params["blocks"], cache["blocks"]))
-            new_cache = {"blocks": nc}
-
-        elif cfg.family == "hybrid":
-            x, new_cache = self._zamba_decode(params, x, positions, pos, cache, w)
-
-        elif cfg.family == "audio":
-            def step(carry, inp):
-                p, kv, xkv = inp
-                h, nkv = _dense_block_fwd(p, carry, cfg, positions=positions,
-                                          causal=True, window=w, prefix_len=0,
-                                          seq_chunk=rt.seq_chunk, cache=kv,
-                                          cache_pos=pos,
-                                          cross_kv=(xkv["k"], xkv["v"]))
-                return h, nkv
-            x, nkv = lax.scan(step, x, (params["blocks"], cache["blocks"],
-                                        cache["cross_kv"]))
-            new_cache = {"blocks": nkv, "cross_kv": cache["cross_kv"]}
-        else:
-            raise ValueError(cfg.family)
-        return x, new_cache
 
     def _zamba_decode(self, params, x, positions, pos, cache, w):
         cfg = self.cfg

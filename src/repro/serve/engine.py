@@ -76,10 +76,13 @@ class DeltaOverlay:
     def n_entries(self) -> int:
         return int((self.slot_ids >= 0).sum())
 
-    def try_admit(self, slot: int, record: Optional[DeltaRecord]) -> bool:
+    def try_admit(self, slot: int, record: Optional[DeltaRecord],
+                  step: int = -1) -> bool:
         """Claim one entry per selected layer for ``slot`` and upload the
         delta rows.  Returns False (writing nothing) if any layer's
-        capacity is exhausted — the caller keeps the request queued."""
+        capacity is exhausted — the caller keeps the request queued.
+        ``step``: the serve step admitting it, the id of its
+        ``serve.upload`` spans."""
         self.release(slot)
         if record is None or record.n_layers == 0:
             self.entries[slot] = []
@@ -103,7 +106,7 @@ class DeltaOverlay:
             plan.append((li, int(free[0])))  # repro: allow[host-sync] -- host np slot bookkeeping (admission time)
         ent = []
         for j, (li, c) in enumerate(plan):
-            if not self._upload_entry(j, li, c, leaves):
+            if not self._upload_entry(j, li, c, leaves, step):
                 # permanent upload failure: roll back this admit's already-
                 # written entries (owner -1 masks the stale leaf rows —
                 # same O(1) trick as release) so no partial user is visible
@@ -119,7 +122,8 @@ class DeltaOverlay:
         self._dirty = True
         return True
 
-    def _upload_entry(self, j: int, li: int, c: int, leaves: dict) -> bool:
+    def _upload_entry(self, j: int, li: int, c: int, leaves: dict,
+                      step: int) -> bool:
         """One entry write with bounded fault retry.  The injected failure
         fires BEFORE the donating write, so a failed attempt consumes no
         buffer and the retry re-reads intact overlay leaves."""
@@ -137,7 +141,7 @@ class DeltaOverlay:
                     return False
                 self.stats["upload_retries"] += 1
                 continue
-            with TraceAnnotation("serve.upload", layer=li):
+            with TraceAnnotation("serve.upload", step=step):
                 rows = {name: jnp.asarray(leaves[name][j])
                         for name in self.leaves}
                 self.leaves = self._write(self.leaves, jnp.int32(li),

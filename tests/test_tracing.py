@@ -229,7 +229,7 @@ def serve_traced(request, serve_world, tmp_path_factory):
 
 
 def test_serve_spans_appear_with_their_step(serve_traced):
-    mode, ((_, _, stats), spans) = serve_traced
+    mode, ((_, reqs, stats), spans) = serve_traced
     steps = _named(spans, "serve.step")
     assert sorted(s.stats["step_num"] for s in steps) == list(
         range(stats["steps"]))
@@ -244,11 +244,11 @@ def test_serve_spans_appear_with_their_step(serve_traced):
     if mode == "shared":
         assert not uploads
     else:
-        admits = _named(spans, "serve.admit")
-        assert uploads and all(any(u.inside(a) for a in admits)
-                               for u in uploads)
-        assert all(0 <= u.stats["layer"] < 3 for u in uploads)
-        assert sum(a.stats["admitted"] for a in admits) == 7
+        admits = {a.id: a for a in _named(spans, "serve.admit")}
+        assert uploads and all(u.inside(admits[u.id]) for u in uploads)
+        # two layers a user: two uploads an admission
+        assert len(uploads) == 2 * len(reqs)
+        assert sum(a.stats["admitted"] for a in admits.values()) == 7
 
 
 def test_serve_step_counters_match_the_loop(serve_traced):
