@@ -161,17 +161,33 @@ def masked_sgd_update(stacked_params: PyTree, stacked_grads: PyTree,
 # fused base + per-slot delta matmul (personalized-delta serving)
 # ---------------------------------------------------------------------------
 
-def base_delta_matmul(x, w, dw, slots, *, block_f: int = 512,
+def delta_live_entries(slots, *, interpret: Optional[bool] = None,
+                       mode: Optional[str] = None):
+    """The kernel's live-first entry table of an (L, C) overlay slot table
+    (``delta_matmul.live_first``), or None where the jnp fallback runs:
+    the delta decode computes it once a step, outside its layer scan, and
+    hands it to every :func:`base_delta_matmul`."""
+    if _resolve_mode(mode, interpret) == "jnp":
+        return None
+    return _dmm.live_first(slots.astype(jnp.int32))
+
+
+def base_delta_matmul(x, w, dw, slots, layer, *, live=None,
+                      transposed: bool = False, block_f: int = 512,
                       interpret: Optional[bool] = None,
                       mode: Optional[str] = None):
-    """``y[b] = x[b] @ w + Σ_{e: slots[e]==b} x[b] @ dw[e]`` — the serving
-    decode projection with per-slot selected-layer deltas (DESIGN.md §9).
+    """``y[b] = x[b] @ w[l] + Σ_{e: slots[l, e]==b} x[b] @ dw[l, e]`` at
+    ``l = layer`` — the serving decode projection with per-slot
+    selected-layer deltas (DESIGN.md §9).
 
-    x: (B, 1, d) decode activations (or (B, d)); w: (d, f) shared base
-    weight; dw: (C, d, f) capacity-C per-layer delta entries; slots: (C,)
-    int32 slot owner per entry, -1 = empty.  The Pallas kernel on TPU, the
-    pure-jnp fallback elsewhere (``mode`` forces either; the two agree to
-    f32 accumulation order).
+    x: (B, 1, d) decode activations (or (B, d)); w: (L, d, f) the stacked
+    shared base weight; dw: (L, C, d, f) the stacked capacity-C overlay
+    leaf; slots: (L, C) int32 slot owner per entry, -1 = free; layer:
+    int32 scalar; live: :func:`delta_live_entries` of ``slots``, else the
+    kernel computes it; transposed: ``w`` and ``dw`` hold each matrix as
+    (f, d).  The stacks are read in place at ``layer``.  The
+    Pallas kernel on TPU, the pure-jnp fallback elsewhere (``mode`` forces
+    either; the two agree to f32 accumulation order).
     """
     m = _resolve_mode(mode, interpret)
     squeeze = x.ndim == 3
@@ -182,9 +198,10 @@ def base_delta_matmul(x, w, dw, slots, *, block_f: int = 512,
         x2 = x
     with jax.named_scope("delta_matmul"):
         if m == "jnp":
-            out = _dmm.base_delta_matmul_2d_jnp(x2, w, dw, slots)
+            out = _dmm.base_delta_matmul_2d_jnp(x2, w, dw, slots, layer,
+                                                transposed=transposed)
         else:
             out = _dmm.base_delta_matmul_2d(
-                x2, w, dw, slots, block_f=block_f,
-                interpret=_auto_interpret(interpret))
+                x2, w, dw, slots, layer, live, transposed=transposed,
+                block_f=block_f, interpret=_auto_interpret(interpret))
     return out[:, None] if squeeze else out

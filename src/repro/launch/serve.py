@@ -18,8 +18,9 @@ Three personalization modes (DESIGN.md §9):
 
 Each pass of the loop is a ``serve.step`` profiler span (its counters:
 occupied slots, slots fed a prompt token whose output is discarded, queued
-requests) holding ``serve.admit``, ``serve.feed``, ``serve.dispatch``,
-``serve.sync`` and ``serve.bookkeep``; PERF.md §3 lists what reads each.
+requests, and in delta mode the live overlay entries) holding
+``serve.admit``, ``serve.feed``, ``serve.dispatch``, ``serve.sync`` and
+``serve.bookkeep``; PERF.md §3 lists what reads each.
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --slots 4 --requests 10 --max-new 16 --mode delta --delta-layers 2
@@ -210,8 +211,11 @@ class SlotServer:
                         # a prompt token but the last: its output is
                         # discarded
                         prompt += p < len(r.prompt) - 1
-                span.set_metadata(active=active, prompt=prompt,
-                                  queued=len(queue))
+                counts = dict(active=active, prompt=prompt,
+                              queued=len(queue))
+                if self.mode == "delta":
+                    counts["entries"] = self.overlay.n_entries
+                span.set_metadata(**counts)
                 # per-slot position vector: each slot decodes at its own
                 # stream position; empty slots idle at 0 and are masked on
                 # refill

@@ -16,6 +16,7 @@ Attention has two execution paths:
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from typing import Optional
 
 import jax
@@ -144,6 +145,54 @@ def per_slot_param(base: Array, drows: Array, slots: Array, B: int) -> Array:
     return (base.astype(jnp.float32)[None] + add)[:, None].astype(base.dtype)
 
 
+@dataclass(frozen=True)
+class LayerDelta:
+    """One scanned layer's view of the per-slot serving overlay.
+
+    The stacks are closed over by the decode scan and read in place at
+    ``layer`` (an int32 scalar): ``slots`` (L, C) owner ids (-1 = free),
+    ``leaves`` {name: (L, C, *shape)} the delta entries, ``weights``
+    {name: (L, d, f)} the base projections that route through
+    :func:`repro.kernels.ops.base_delta_matmul`, ``live`` the kernel's
+    entry table of ``slots`` (:func:`repro.kernels.ops.delta_live_entries`,
+    computed once a step).  Leaf names are the sub-block's own once
+    :meth:`sub_block` strips its prefix.
+    """
+    layer: Array
+    slots: Array
+    leaves: dict
+    weights: dict
+    live: Optional[tuple] = None
+
+    def sub_block(self, prefix: str) -> "LayerDelta":
+        n = len(prefix)
+        return LayerDelta(
+            self.layer, self.slots,
+            {k[n:]: v for k, v in self.leaves.items() if k.startswith(prefix)},
+            {k[n:]: v for k, v in self.weights.items()
+             if k.startswith(prefix)}, self.live)
+
+    def proj(self, h: Array, name: str) -> Array:
+        from repro.kernels import ops as _kops
+        w, dw = self.weights[name], self.leaves[name]
+        # the overlay stores a matrix the TPU lays out d-minor as (f, d):
+        # the base weight is then read as its transpose, which is the
+        # same bytes
+        transposed = dw.shape[-2:] != w.shape[-2:]
+        if transposed:
+            w = jnp.swapaxes(w, 1, 2)
+        return _kops.base_delta_matmul(h, w, dw, self.slots, self.layer,
+                                       live=self.live, transposed=transposed)
+
+    def param(self, base: Array, name: str, B: int) -> Array:
+        """:func:`per_slot_param` of a small leaf at this layer: a gather of
+        row ``layer`` of its (L, C, *shape) entries."""
+        def row(a):
+            return lax.dynamic_index_in_dim(a, self.layer, keepdims=False)
+        return per_slot_param(base, row(self.leaves[name]), row(self.slots),
+                              B)
+
+
 def attend_full(q: Array, k: Array, v: Array, bias: Array, scale: float) -> Array:
     """q: (B,Sq,H,hd)  k/v: (B,Sk,K,hd)  bias: (Sq,Sk) shared, or
     (B,Sq,Sk) per-slot (the serving decode path). GQA via reshape."""
@@ -233,8 +282,8 @@ def attention_fwd(p: dict, x: Array, cfg: ArchConfig, *,
                   cache_pos: Optional[Array] = None,
                   causal: bool = True, window: int = 0, prefix_len: int = 0,
                   cross_kv: Optional[tuple] = None, seq_chunk: int = 1024,
-                  remat_chunk: bool = False, delta: Optional[dict] = None,
-                  delta_slots: Optional[Array] = None):
+                  remat_chunk: bool = False,
+                  delta: Optional[LayerDelta] = None):
     """One attention sub-block (pre-norm, residual added by caller).
 
     cache: {"k": (B,W,Kh,hd), "v": ..., "pos": (W,) int32} — decode mode
@@ -244,25 +293,22 @@ def attention_fwd(p: dict, x: Array, cfg: ArchConfig, *,
     stream position.  cross_kv: precomputed (k, v) for encoder-decoder
     cross-attention.
 
-    delta/delta_slots: capacity-C per-slot parameter deltas for this layer
-    ({leaf_name: (C, *shape)} + (C,) owner slot ids, -1 = empty) — the
-    personalized-delta serving overlay (DESIGN.md §9); projections route
-    through :func:`repro.kernels.ops.base_delta_matmul`.
+    delta: this layer's view of the personalized-delta serving overlay
+    (:class:`LayerDelta`, DESIGN.md §9); its projections route through
+    :func:`repro.kernels.ops.base_delta_matmul` and are absent from ``p``.
     """
-    from repro.kernels import ops as _kops
     B, S, d = x.shape
     H, Kh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     scale = 1.0 / math.sqrt(hd)
 
     def proj(h_, name):
-        if delta is not None and name in delta:
-            return _kops.base_delta_matmul(h_, p[name], delta[name],
-                                           delta_slots)
+        if delta is not None and name in delta.weights:
+            return delta.proj(h_, name)
         return h_ @ p[name]
 
     ln = p["ln"]
-    if delta is not None and "ln" in delta:
-        ln = per_slot_param(ln, delta["ln"], delta_slots, B)
+    if delta is not None and "ln" in delta.leaves:
+        ln = delta.param(ln, "ln", B)
     h = rms_norm(x, ln, cfg.norm_eps)
     q = proj(h, "wq").reshape(B, S, H, hd)
     if cross_kv is None:
@@ -272,9 +318,8 @@ def attention_fwd(p: dict, x: Array, cfg: ArchConfig, *,
         k, v = cross_kv
     if cfg.qkv_bias:
         def bias_term(name, nh):
-            if delta is not None and name in delta:
-                return per_slot_param(p[name], delta[name], delta_slots,
-                                      B).reshape(B, 1, nh, hd)
+            if delta is not None and name in delta.leaves:
+                return delta.param(p[name], name, B).reshape(B, 1, nh, hd)
             return p[name].reshape(nh, hd)
         q = q + bias_term("bq", H)
         if cross_kv is None:
@@ -363,24 +408,20 @@ def mlp_param_shapes(cfg: ArchConfig, d_ff: Optional[int] = None) -> dict:
 
 
 def mlp_fwd(p: dict, x: Array, cfg: ArchConfig, *,
-            delta: Optional[dict] = None,
-            delta_slots: Optional[Array] = None) -> Array:
-    from repro.kernels import ops as _kops
-
+            delta: Optional[LayerDelta] = None) -> Array:
     def proj(h_, name):
-        if delta is not None and name in delta:
-            return _kops.base_delta_matmul(h_, p[name], delta[name],
-                                           delta_slots)
+        if delta is not None and name in delta.weights:
+            return delta.proj(h_, name)
         return h_ @ p[name]
 
     ln = p["ln"]
-    if delta is not None and "ln" in delta:
-        ln = per_slot_param(ln, delta["ln"], delta_slots, x.shape[0])
+    if delta is not None and "ln" in delta.leaves:
+        ln = delta.param(ln, "ln", x.shape[0])
     h = rms_norm(x, ln, cfg.norm_eps)
     act = act_fn(cfg.mlp_act)
     if cfg.mlp_act == "gelu_plain":
         return proj(act(proj(h, "wi")), "wo")
-    ff = p["wi"].shape[-1] // 2
     gu = proj(h, "wi")
+    ff = gu.shape[-1] // 2
     gate, up = gu[..., :ff], gu[..., ff:]
     return proj(act(gate) * up, "wo")

@@ -85,6 +85,14 @@ def supports_delta_decode(cfg: ArchConfig) -> bool:
     return cfg.family in ("dense", "vlm")
 
 
+def delta_projections(blocks: dict, leaves: dict) -> dict:
+    """The stacked (L, d, f) base projections of ``blocks`` that the delta
+    decode reads in place through ``ops.base_delta_matmul``: those the
+    overlay ``leaves`` carry entries for."""
+    return {n: v for n, v in blocks.items()
+            if n in leaves and len(v.shape) == 3}
+
+
 def segment_cuts(cut: int, cfg: ArchConfig) -> dict[str, int]:
     """Per-segment frozen-prefix lengths for a global mask-index ``cut``.
 
@@ -296,26 +304,23 @@ def _dense_block_fwd(p: dict, x: Array, cfg: ArchConfig, *, positions,
                      causal, window, prefix_len, seq_chunk,
                      cache=None, cache_pos=None, cross_kv=None,
                      remat_chunk=False, delta=None):
-    # delta: (slots (C,), {leaf_name: (C, *shape)}) — this layer's row of
-    # the per-slot serving overlay; leaf names are split by sub-block prefix
-    dslots = dattn = dmlp = None
+    # delta: this layer's B.LayerDelta view of the per-slot serving
+    # overlay; its leaf names are split by sub-block prefix
+    dattn = dmlp = None
     if delta is not None:
-        dslots, dleaves = delta
-        dattn = _take(dleaves, "attn_") or None
-        dmlp = _take(dleaves, "mlp_") or None
+        dattn, dmlp = delta.sub_block("attn_"), delta.sub_block("mlp_")
     attn_out, new_kv = B.attention_fwd(
         _take(p, "attn_"), x, cfg, positions=positions, cache=cache,
         cache_pos=cache_pos, causal=causal, window=window,
         prefix_len=prefix_len, seq_chunk=seq_chunk, remat_chunk=remat_chunk,
-        delta=dattn, delta_slots=dslots)
+        delta=dattn)
     x = x + attn_out
     if "xattn_ln" in p:   # whisper decoder cross-attention
         xo, _ = B.attention_fwd(_take(p, "xattn_"), x, cfg, positions=positions,
                                 cross_kv=cross_kv, causal=False,
                                 seq_chunk=seq_chunk)
         x = x + xo
-    x = x + B.mlp_fwd(_take(p, "mlp_"), x, cfg, delta=dmlp,
-                      delta_slots=dslots)
+    x = x + B.mlp_fwd(_take(p, "mlp_"), x, cfg, delta=dmlp)
     return x, new_kv
 
 
@@ -771,7 +776,8 @@ class Model:
         int32 owner ids (-1 = empty), "leaves": {name: (L, C, *shape)}}``
         — capacity-C delta entries per scanned layer, consumed inside the
         one jitted program so slots with *different* deltas batch together
-        (DESIGN.md §9).
+        (DESIGN.md §9).  The scan closes over the overlay and the base
+        projections and carries the layer index instead.
 
         Returns (logits (B,V), new_cache).
         """
@@ -789,17 +795,29 @@ class Model:
         w = window or cfg.sliding_window
         with jax.named_scope("decode_blocks"):
             if cfg.family in ("dense", "vlm"):
+                blocks, stacked, live = params["blocks"], {}, None
+                if delta is not None:
+                    from repro.kernels import ops as _kops
+                    # the overlay and the base projections it corrects are
+                    # closed over and read in place at the scanned layer
+                    # index: the scan slices neither
+                    stacked = delta_projections(blocks, delta["leaves"])
+                    blocks = {n: v for n, v in blocks.items()
+                              if n not in stacked}
+                    live = _kops.delta_live_entries(delta["slots"])
+
                 def step(carry, inp):
-                    p, kv = inp[:2]
-                    dl = (inp[2], inp[3]) if delta is not None else None
+                    p, kv, l = inp
+                    dl = (B.LayerDelta(l, delta["slots"], delta["leaves"],
+                                       stacked, live)
+                          if delta is not None else None)
                     h, new_kv = _dense_block_fwd(p, carry, cfg, positions=positions,
                                                  causal=True, window=w, prefix_len=0,
                                                  seq_chunk=rt.seq_chunk, cache=kv,
                                                  cache_pos=pos, delta=dl)
                     return h, new_kv
-                xs = (params["blocks"], cache["blocks"])
-                if delta is not None:
-                    xs = xs + (delta["slots"], delta["leaves"])
+                xs = (blocks, cache["blocks"],
+                      jnp.arange(cfg.n_layers, dtype=jnp.int32))
                 x, new_kv = lax.scan(step, x, xs)
                 new_cache = {"blocks": new_kv}
 

@@ -4,11 +4,12 @@ Two pieces:
 
 * :class:`DeltaOverlay` — the capacity-C per-layer delta entry table the
   fused decode consumes.  Device state is ``{"slots": (L, C) int32 owner
-  slot ids (-1 = free), "leaves": {name: (L, C, *shape)}}``; a host-side
-  ``slot_ids`` mirror makes admit/release pure bookkeeping.  Admitting a
+  slot ids (-1 = free), "leaves": {name: (L, C, *shape)}}`` (shapes from
+  :func:`overlay_shapes`); a host-side ``slot_ids`` mirror makes
+  admit/release pure bookkeeping.  Admitting a
   user uploads only *their* delta rows (donated in-place entry writes);
-  releasing a slot just marks entries free — stale leaf rows are masked
-  by the -1 owner id inside the kernel, so eviction is O(1) host work.
+  releasing a slot just marks entries free — the kernel neither reads nor
+  applies a free entry's stale rows, so eviction is O(1) host work.
 
 * :func:`serve_suite` — the jitted decode programs, registered in the
   same cache as the training suites (``core.client._JIT_CACHE``) so
@@ -25,19 +26,36 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.client import _JIT_CACHE, _JIT_STATS
+from repro.kernels.delta_matmul import d_minor
 from repro.models.model import Model, _block_shapes, supports_delta_decode
 from repro.serve.deltas import DeltaRecord
 
 
+def overlay_shapes(cfg) -> dict:
+    """Per-layer shape of each overlay leaf: a block leaf's own, except a
+    (d, f) matrix that a TPU lays out d-minor, stored as (f, d) so that
+    ``delta_matmul`` reads it in place, row-major (kernels/delta_matmul.py
+    ``d_minor``)."""
+    return {name: (tuple(shp[::-1]) if len(shp) == 2 and d_minor(*shp)
+                   else tuple(shp))
+            for name, shp in _block_shapes(cfg, "dense").items()}
+
+
 def _write_entry(leaves: dict, l, c, rows: dict) -> dict:
-    """Set entry (l, c) of every overlay leaf to the user's delta row.
+    """Set entry (l, c) of every overlay leaf to the user's delta row (a
+    matrix transposed where the leaf stores it as (f, d)).
 
     Jitted with the leaf dict donated: one compiled program per overlay
     shape, and each admit transfers only the (k,)-layer delta rows —
     never the (L, C, …) table.
     """
-    return {name: leaf.at[l, c].set(rows[name].astype(leaf.dtype))
-            for name, leaf in leaves.items()}
+    out = {}
+    for name, leaf in leaves.items():
+        row = rows[name]
+        if row.shape != leaf.shape[2:]:          # stored as (f, d)
+            row = row.T
+        out[name] = leaf.at[l, c].set(row.astype(leaf.dtype))
+    return out
 
 
 class DeltaOverlay:
@@ -56,14 +74,16 @@ class DeltaOverlay:
         if not supports_delta_decode(model.cfg):
             raise ValueError(
                 f"family {model.cfg.family!r} has no delta-decode path")
-        shapes = _block_shapes(model.cfg, "dense")   # per-layer leaf shapes
         L = model.cfg.n_layers
         self.capacity = int(capacity)
         self.leaves = {
-            name: jnp.zeros((L, self.capacity) + tuple(shp), jnp.float32)
-            for name, shp in shapes.items()}
+            name: jnp.zeros((L, self.capacity) + shp, jnp.float32)
+            for name, shp in overlay_shapes(model.cfg).items()}
         self.slot_ids = np.full((L, self.capacity), -1, np.int32)
         self.entries: dict[int, list[tuple[int, int]]] = {}
+        # live entries, which the decode's delta_matmul reads: kept by
+        # admit and release so the serve loop never sums the table
+        self.n_entries = 0
         self._slots_dev = jnp.asarray(self.slot_ids)
         self._dirty = False
         self._write = jax.jit(_write_entry, donate_argnums=0)
@@ -71,10 +91,6 @@ class DeltaOverlay:
         self.max_upload_retries = int(max_upload_retries)
         self.stats = {"upload_retries": 0, "failed_admits": 0}
         self._upload_seq = 0     # monotone entry-write counter (fault lane)
-
-    @property
-    def n_entries(self) -> int:
-        return int((self.slot_ids >= 0).sum())
 
     def try_admit(self, slot: int, record: Optional[DeltaRecord],
                   step: int = -1) -> bool:
@@ -119,6 +135,7 @@ class DeltaOverlay:
             self.slot_ids[li, c] = slot
             ent.append((li, c))
         self.entries[slot] = ent
+        self.n_entries += len(ent)
         self._dirty = True
         return True
 
@@ -151,6 +168,7 @@ class DeltaOverlay:
     def release(self, slot: int) -> None:
         for li, c in self.entries.pop(slot, []):
             self.slot_ids[li, c] = -1
+            self.n_entries -= 1
             self._dirty = True
 
     def device(self) -> dict:
@@ -257,7 +275,7 @@ def serve_program_specs(model: Model, *, slots: int = 3, capacity: int = 2,
             static_argnums=(4,),
             meta=dict(common, kind="serve_decode", batch=b)))
     if supports_delta_decode(cfg):
-        shapes = _block_shapes(cfg, "dense")
+        shapes = overlay_shapes(cfg)
         for b in (slots, 2 * slots):
             tok, pos = toks_pos(b)
             for C in capacities:
@@ -275,7 +293,7 @@ def serve_program_specs(model: Model, *, slots: int = 3, capacity: int = 2,
         leaves = {name: SDS((L, capacity) + tuple(shp), jnp.float32)
                   for name, shp in shapes.items()}
         rows = {name: SDS(tuple(shp), jnp.float32)
-                for name, shp in shapes.items()}
+                for name, shp in _block_shapes(cfg, "dense").items()}
         specs.append(dict(
             base, name="serve_write_delta_entry",
             fn=jax.jit(_write_entry, donate_argnums=0),

@@ -2,6 +2,8 @@
 
 All kernels run in interpret mode on CPU (the kernel bodies execute exactly
 as they would on TPU, minus the hardware tiling)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -197,79 +199,167 @@ def test_ops_attention_ssd_mode_dispatch():
 
 
 DELTA_MM_CASES = [
-    # (B, d, f, C, block_f, dtype)
+    # (B, d, f, C, block_f, dtype): one layer, its last entry free
     (4, 64, 128, 2, 512, jnp.float32),
     (6, 128, 512, 4, 128, jnp.float32),
-    (3, 32, 100, 1, 64, jnp.float32),      # f padded to 128 lanes
+    (3, 32, 100, 1, 64, jnp.float32),      # f no 128-multiple: whole
     (8, 4096, 128, 8, 128, jnp.float32),   # d tiled: two contraction steps
     (4, 64, 256, 3, 512, jnp.bfloat16),
     (2, 16, 48, 2, 32, jnp.bfloat16),
 ]
+# stacked overlays: (..., L, layer, live, transposed) — the kernel reads
+# layer ``layer`` of (L, d, f) / (L, C, d, f) stacks whose other layers hold
+# other data, or of (L, f, d) / (L, C, f, d) ones when ``transposed`` (the
+# overlay's storage of a matrix the TPU lays out d-minor); ``live`` is that
+# layer's owner pattern: no live entry, one, all, or live entries behind
+# free ones ("late")
+DELTA_MM_STACKED = [
+    (4, 64, 128, 4, 512, jnp.float32, 3, 0, "none", False),
+    (4, 64, 256, 4, 128, jnp.float32, 3, 2, "one", False),
+    (6, 128, 512, 4, 128, jnp.float32, 2, 1, "all", False),
+    (6, 128, 256, 6, 128, jnp.bfloat16, 3, 1, "late", False),
+    (5, 200, 100, 3, 64, jnp.float32, 2, 1, "late", False),   # whole axes
+    (4, 100, 64, 3, 512, jnp.float32, 2, 1, "late", True),
+    (6, 256, 512, 4, 128, jnp.bfloat16, 2, 0, "all", True),   # both tiled
+    (8, 960, 960, 8, 512, jnp.bfloat16, 2, 1, "late", False),  # SmolLM q / o
+    (8, 960, 320, 8, 512, jnp.bfloat16, 2, 0, "one", True),    # k / v
+    (4, 2560, 960, 4, 512, jnp.bfloat16, 2, 1, "all", True),   # down
+]
 
 
-def _delta_mm_inputs(B, d, f, C, dtype, seed=5):
+def _delta_mm_params():
+    def case_id(c):
+        head = "-".join(str(v) for v in c[:5]) + "-" + c[5].__name__
+        if len(c) == 6:
+            return head
+        return head + "-L{}-l{}-{}".format(*c[6:9]) + ("-T" if c[9] else "")
+    return [pytest.param(*c[:6], c[6:] or None, id=case_id(c))
+            for c in DELTA_MM_CASES + DELTA_MM_STACKED]
+
+
+def _delta_mm_inputs(B, d, f, C, dtype, stack=None, seed=5):
+    """x (B, d), w (L, d, f), dw (L, C, d, f), slots (L, C), layer.  The
+    serving invariant holds in every layer: ≤ 1 entry per slot."""
+    L, layer, live = (stack or (1, 0, None))[:3]
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     x = jax.random.normal(ks[0], (B, d), dtype)
-    w = jax.random.normal(ks[1], (d, f), dtype)
-    dw = (jax.random.normal(ks[2], (C, d, f)) * 0.1).astype(dtype)
-    # serving invariant: ≤1 entry per slot per layer — distinct owners,
-    # with one entry left empty (-1) when capacity allows
-    slots = np.random.RandomState(seed).permutation(B)[:C].astype(np.int32)
-    if C > 1:
-        slots[-1] = -1
-    return x, w, dw, jnp.asarray(slots)
+    w = jax.random.normal(ks[1], (L, d, f), dtype)
+    dw = (jax.random.normal(ks[2], (L, C, d, f)) * 0.1).astype(dtype)
+    rs = np.random.RandomState(seed)
+    owners = rs.permutation(B)[:C].astype(np.int32)
+    if live is None and C > 1:
+        owners[-1] = -1
+    elif live == "none":
+        owners[:] = -1
+    elif live == "one":
+        owners[np.arange(C) != C // 2] = -1
+    elif live == "late":
+        owners[:C // 2] = -1
+    slots = np.stack([np.where(rs.rand(C) < 0.5, -1,
+                               rs.permutation(B)[:C]).astype(np.int32)
+                      for _ in range(L)])
+    slots[layer] = owners
+    return x, w, dw, jnp.asarray(slots), jnp.int32(layer)
 
 
-@pytest.mark.parametrize("B,d,f,C,block_f,dtype", DELTA_MM_CASES)
-def test_base_delta_matmul_sweep(B, d, f, C, block_f, dtype):
-    """Fused base+delta GEMM vs the unfused oracle: y[b] = x[b]@W, plus
-    x[b]@dw[e] for the entry e owned by slot b (DESIGN.md §9)."""
+def _transposed(stack) -> bool:
+    return bool(stack and stack[3])
+
+
+def _stored(w, dw, stack):
+    """The stacks as the kernel reads them: each matrix as (f, d) when the
+    case stores it transposed."""
+    if _transposed(stack):
+        return jnp.swapaxes(w, 1, 2), jnp.swapaxes(dw, 2, 3)
+    return w, dw
+
+
+@pytest.mark.parametrize("B,d,f,C,block_f,dtype,stack", _delta_mm_params())
+def test_base_delta_matmul_sweep(B, d, f, C, block_f, dtype, stack):
+    """Fused base+delta GEMM vs the unfused oracle: y[b] = x[b]@W[l], plus
+    x[b]@dw[l, e] for the entry e owned by slot b (DESIGN.md §9)."""
     from repro.kernels.delta_matmul import base_delta_matmul_2d
-    x, w, dw, slots = _delta_mm_inputs(B, d, f, C, dtype)
-    out = base_delta_matmul_2d(x, w, dw, slots, block_f=block_f,
-                               interpret=True)
+    x, w, dw, slots, layer = _delta_mm_inputs(B, d, f, C, dtype, stack)
+    out = base_delta_matmul_2d(x, *_stored(w, dw, stack), slots, layer,
+                               transposed=_transposed(stack),
+                               block_f=block_f, interpret=True)
+    l = int(layer)
     x32 = np.asarray(x, np.float32)
-    want = x32 @ np.asarray(w, np.float32)
-    for e, s in enumerate(np.asarray(slots)):
+    want = x32 @ np.asarray(w[l], np.float32)
+    for e, s in enumerate(np.asarray(slots[l])):
         if s >= 0:
-            want[s] += x32[s] @ np.asarray(dw[e], np.float32)
+            want[s] += x32[s] @ np.asarray(dw[l, e], np.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(out, np.float32), want,
                                atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("B,d,f,C,block_f,dtype", DELTA_MM_CASES)
+@pytest.mark.parametrize("B,d,f,C,block_f,dtype,stack", _delta_mm_params())
 def test_base_delta_matmul_jnp_fallback_bit_identical(B, d, f, C, block_f,
-                                                      dtype):
+                                                      dtype, stack):
     """Kernel (interpret) and the jitted jnp fallback compute the same
-    sums; the kernel accumulates them over (block_d, block_f) tiles, the
-    fallback in one contraction per term.  So they agree to f32
-    accumulation order: within 1e-5 relative in f32, and within one bf16
-    ulp (2^-7 relative) where the f32 result is rounded to bf16."""
+    sums; the kernel accumulates them over (block_d, block_f) tiles and
+    live entries only, the fallback in one contraction per term over all C
+    entries.  So they agree to f32 accumulation order: within 1e-5
+    relative in f32, and within one bf16 ulp (2^-7 relative) where the f32
+    result is rounded to bf16."""
     from repro.kernels.delta_matmul import (base_delta_matmul_2d,
                                             base_delta_matmul_2d_jnp)
-    x, w, dw, slots = _delta_mm_inputs(B, d, f, C, dtype)
-    kernel = base_delta_matmul_2d(x, w, dw, slots, block_f=block_f,
-                                  interpret=True)
-    fallback = jax.jit(base_delta_matmul_2d_jnp)(x, w, dw, slots)
+    x, w, dw, slots, layer = _delta_mm_inputs(B, d, f, C, dtype, stack)
+    w, dw = _stored(w, dw, stack)
+    t = _transposed(stack)
+    kernel = base_delta_matmul_2d(x, w, dw, slots, layer, transposed=t,
+                                  block_f=block_f, interpret=True)
+    fallback = jax.jit(functools.partial(
+        base_delta_matmul_2d_jnp, transposed=t))(x, w, dw, slots, layer)
     tol = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(kernel, np.float32),
                                np.asarray(fallback, np.float32),
                                rtol=tol, atol=tol * float(jnp.sqrt(d)))
 
 
+def test_delta_matmul_d_minor_matches_the_tpu_layout():
+    """The overlay stores transposed exactly the matrices whose default
+    TPU layout is d-minor (read off a compile for a described v5e): SmolLM
+    k / v and down, not q / o / gate-up; no TinyLlama-1.1B projection."""
+    from repro.kernels.delta_matmul import d_minor
+    smollm = {(960, 960): False, (960, 320): True, (960, 5120): False,
+              (2560, 960): True}
+    tinyllama = {(2048, 2048): False, (2048, 256): False,
+                 (2048, 5632): False, (5632, 2048): False}
+    for (d, f), want in {**smollm, **tinyllama}.items():
+        assert d_minor(d, f) == want, (d, f)
+        assert not (d_minor(d, f) and d_minor(f, d))
+
+
+def test_delta_matmul_live_first():
+    """Entries are visited live-first; past the live ones the fetch index
+    repeats the last live entry's, so the pipeline reads nothing more."""
+    from repro.kernels.delta_matmul import live_first
+    slots = jnp.asarray([[-1, 3, -1, 0], [-1, -1, -1, -1], [2, 1, 0, 5]],
+                        jnp.int32)
+    fetch, owner = live_first(slots)
+    np.testing.assert_array_equal(
+        np.asarray(fetch).reshape(3, 4), [[1, 3, 3, 3], [0, 0, 0, 0],
+                                          [0, 1, 2, 3]])
+    np.testing.assert_array_equal(
+        np.asarray(owner).reshape(3, 4), [[3, 0, -1, -1], [-1] * 4,
+                                          [2, 1, 0, 5]])
+
+
 def test_ops_base_delta_matmul_dispatch():
     """The ops-layer wrapper: (B,1,d) decode activations route through the
     2-D path; empty slot table degenerates to the plain GEMM exactly."""
-    x, w, dw, slots = _delta_mm_inputs(3, 16, 32, 2, jnp.float32)
-    out3 = ops.base_delta_matmul(x[:, None], w, dw, slots, mode="jnp")
-    out2 = ops.base_delta_matmul(x, w, dw, slots, mode="jnp")
+    x, w, dw, slots, layer = _delta_mm_inputs(3, 16, 32, 2, jnp.float32,
+                                              (2, 1, None, False))
+    out3 = ops.base_delta_matmul(x[:, None], w, dw, slots, layer, mode="jnp")
+    out2 = ops.base_delta_matmul(x, w, dw, slots, layer, mode="jnp")
     np.testing.assert_array_equal(np.asarray(out3[:, 0]), np.asarray(out2))
-    empty = ops.base_delta_matmul(x, w, dw, jnp.full((2,), -1, jnp.int32),
-                                  mode="jnp")
+    empty = ops.base_delta_matmul(x, w, dw, jnp.full((2, 2), -1, jnp.int32),
+                                  layer, mode="jnp")
     np.testing.assert_allclose(
         np.asarray(empty),
-        np.asarray(x, np.float32) @ np.asarray(w, np.float32), atol=1e-5)
+        np.asarray(x, np.float32) @ np.asarray(w[1], np.float32), atol=1e-5)
 
 
 def _small_world():

@@ -122,6 +122,53 @@ def test_overlay_capacity_admit_release():
     assert np.asarray(dev["slots"]).max() == 1
 
 
+def _eqns(jaxpr, skip=("pallas_call",)):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, except
+    inside the primitives named in ``skip``."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name in skip:
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, skip)
+
+
+def test_delta_decode_reads_overlay_in_place(monkeypatch):
+    """The delta decode's jaxpr with the Pallas kernel forced: nothing is
+    padded outside the kernel, and the overlay leaves and the base
+    projections they correct enter the decode scan as loop constants (the
+    kernel reads them in place), not as its per-layer ``xs``."""
+    from repro.kernels import ops
+    from repro.models.model import delta_projections
+    monkeypatch.setattr(ops, "_resolve_mode", lambda mode, interpret: "pallas")
+    model, params = _world()
+    delta = DeltaOverlay(model, capacity=2).device()
+    cache = model.init_cache(3, 16, per_slot=True)
+    vec = jnp.zeros(3, jnp.int32)
+    closed = jax.make_jaxpr(lambda p, c, d: model.decode_step(
+        p, vec, vec, c, delta=d))(params, cache, delta)
+    eqns = list(_eqns(closed.jaxpr))
+    assert any(e.primitive.name == "pallas_call" for e in eqns)
+    assert not [e for e in eqns if e.primitive.name == "pad"]
+
+    paths = [jax.tree_util.keystr(k) for k, _ in
+             jax.tree_util.tree_flatten_with_path((params, cache, delta))[0]]
+    wanted = ({f"[0]['blocks']['{n}']" for n in
+               delta_projections(params["blocks"], delta["leaves"])}
+              | {f"[2]['leaves']['{n}']" for n in delta["leaves"]})
+    read_in_place = [v for v, n in zip(closed.jaxpr.invars, paths)
+                     if n in wanted]
+    assert len(read_in_place) == len(wanted) == 6 + len(delta["leaves"])
+    scan, = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+    consts = set(scan.invars[:scan.params["num_consts"]])
+    xs = set(scan.invars[scan.params["num_consts"]
+                         + scan.params["num_carry"]:])
+    assert all(v in consts and v not in xs for v in read_in_place)
+
+
 def test_delta_record_autodetect_and_materialize():
     """layers=None detects exactly the perturbed rows; store.materialize
     reproduces the tuned tree on those rows and leaves the rest alone."""
