@@ -6,134 +6,112 @@ as a client trains, weight gradients only of selected layers, attention
 over the keys a query may see, weights read once per decode step, and each
 user's delta rows only while the user is decoding.  Recomputation,
 padding and work on unselected rows are never counted, so an honest
-change cannot push a share past 100%.
+change cannot push a share past 100%.  Each count is written over the
+per-layer quantities of the configuration's family (``geometry``), so a
+layer unlike the others (a leading dense layer, experts of which a token
+meets only some) counts as what it is.
 
 Parameters are counted in the configuration's dtype (2 bytes for bf16).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from pathlib import Path
 
 import numpy as np
 
+from bench.harness import ROOT, family_module
 
-@dataclass(frozen=True)
-class Geometry:
-    n_layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    ff: int
-    vocab: int
-    gated: bool              # SwiGLU: wi is (d, 2·ff)
-    n_classes: int = 0
-    param_bytes: int = 2
 
-    @classmethod
-    def from_config(cls, c: dict) -> "Geometry":
-        d, h = c["hidden_size"], c["num_attention_heads"]
-        return cls(n_layers=c["num_hidden_layers"], d=d, heads=h,
-                   kv_heads=c.get("num_key_value_heads", h),
-                   head_dim=c.get("head_dim", d // h),
-                   ff=c["intermediate_size"], vocab=c["vocab_size"],
-                   gated=c["hidden_act"] == "silu",
-                   n_classes=int(c.get("program", {}).get("n_classes", 0)),
-                   param_bytes={"bfloat16": 2, "float16": 2,
-                                "float32": 4}[c["torch_dtype"]])
+def geometry(config: dict, root: Path = ROOT):
+    """The configuration's ``Geometry``, from its family's
+    ``bench/geometry/<family>.py``: ``n_layers``, ``param_bytes``, and per
+    layer l ``matmul_flops(l)`` (a token's forward matrix FLOPs through the
+    weights it meets: the active experts, not all), ``attn_flops(l,
+    keys)``, ``layer_params(l)`` (what the update and the norm read),
+    ``cache_bytes(l)`` (per position), ``projections(l)`` ((d_in, d_out)
+    of each product ``delta_matmul`` computes); and the heads'
+    ``head_params``, ``lm_head_flops`` (a token) and ``class_head_flops``
+    (a pooled sequence)."""
+    return family_module(config, "geometry", root).Geometry.from_config(config)
 
-    def projections(self) -> list[tuple[int, int]]:
-        """(d_in, d_out) of a layer's six matrix products."""
-        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
-        wi = 2 * self.ff if self.gated else self.ff
-        return [(self.d, q), (self.d, kv), (self.d, kv), (q, self.d),
-                (self.d, wi), (self.ff, self.d)]
 
-    @property
-    def matmul_params(self) -> int:
-        """Weights of a layer's matrix products."""
-        return sum(a * b for a, b in self.projections())
-
-    @property
-    def layer_params(self) -> int:
-        """Every parameter of a layer: the products and two norm scales."""
-        return self.matmul_params + 2 * self.d
-
-    def attn_flops(self, keys: float) -> float:
-        """Scores and the weighted sum for one query over ``keys`` keys."""
-        return 4.0 * keys * self.heads * self.head_dim
+def _per_layer(g, f) -> np.ndarray:
+    return np.array([f(l) for l in range(g.n_layers)], np.float64)
 
 
 # ---------------------------------------------------------------------------
 # the federated round
 # ---------------------------------------------------------------------------
 
-def fl_round_flops(g: Geometry, masks: np.ndarray, probed: int, *,
+def fl_round_flops(g, masks: np.ndarray, probed: int, *,
                    tau: int, batch: int, seq: int, eval_rows: int) -> float:
     """One round: every client's τ update steps (forward over all layers,
     the activation backward down to its lowest selected layer, weight
     gradients of its selected layers), the probe's full forward and
     backward for ``probed`` clients, and the eval forward."""
-    per_tok_fwd = 2.0 * g.matmul_params + g.attn_flops(seq)
-    per_tok_act_bwd = 2.0 * g.matmul_params + 2.0 * g.attn_flops(seq)
-    per_tok_wgrad = 2.0 * g.matmul_params
-    head_fwd = 2.0 * g.d * g.n_classes          # per sequence, pooled
-    L = g.n_layers
+    mm = _per_layer(g, g.matmul_flops)
+    attn = _per_layer(g, lambda l: g.attn_flops(l, seq))
+    fwd = float((mm + attn).sum())                  # a token, every layer
+    act_bwd = mm + 2.0 * attn                       # a token, per layer
+    head_fwd = g.class_head_flops                   # per sequence, pooled
     upd_tok = tau * batch * seq
     total = 0.0
     for m in np.asarray(masks):
         sel = np.flatnonzero(m > 0)
-        total += upd_tok * L * per_tok_fwd + tau * batch * head_fwd
+        total += upd_tok * fwd + tau * batch * head_fwd
         if sel.size:
-            total += upd_tok * ((L - sel[0]) * per_tok_act_bwd
-                                + sel.size * per_tok_wgrad)
+            total += upd_tok * (act_bwd[sel[0]:].sum() + mm[sel].sum())
     probe_tok = batch * seq
-    total += probed * (probe_tok * L * (per_tok_fwd + per_tok_act_bwd
-                                        + per_tok_wgrad) + batch * head_fwd)
-    total += eval_rows * (seq * L * per_tok_fwd + head_fwd)
-    return total
+    total += probed * (probe_tok * (fwd + act_bwd.sum() + mm.sum())
+                       + batch * head_fwd)
+    total += eval_rows * (seq * fwd + head_fwd)
+    return float(total)
 
 
-def masked_update_bytes(g: Geometry, masks: np.ndarray, tau: int) -> float:
+def masked_update_bytes(g, masks: np.ndarray, tau: int) -> float:
     """θ ← θ − η·g over each client's selected layers, τ times: read θ and
     g, write θ."""
-    sel = float(np.asarray(masks).sum())
-    return tau * sel * g.layer_params * 3 * g.param_bytes
+    sel = np.asarray(masks, np.float64).sum(0)      # clients per layer
+    return tau * float(sel @ _per_layer(g, g.layer_params)) * 3 * g.param_bytes
 
 
-def layer_grad_norm_bytes(g: Geometry, probed: int) -> float:
+def layer_grad_norm_bytes(g, probed: int) -> float:
     """Σ g² per layer over every layer's gradient, once per probed client."""
-    return probed * g.n_layers * g.layer_params * g.param_bytes
+    return probed * float(_per_layer(g, g.layer_params).sum()) * g.param_bytes
 
 
 # ---------------------------------------------------------------------------
 # the decode step
 # ---------------------------------------------------------------------------
 
-def decode_step_counts(g: Geometry, active: list, delta_layers=None
+def decode_step_counts(g, active: list, delta_layers=None
                        ) -> tuple[float, float]:
     """(flops, bytes) of one decode step.  ``active``: (user, position) per
     decoding slot; ``delta_layers[user]``: that user's delta layers.
-    Bytes: the block weights and the tied embedding table once, each
-    active user's delta rows, and the keys and values of the positions
-    each slot attends to (read) and writes."""
-    L, pb = g.n_layers, g.param_bytes
-    kv_row = 2 * g.kv_heads * g.head_dim * pb          # K and V, one layer
+    Bytes: the layers' weights and the head's once, each active user's
+    delta rows, and the cache of the positions each slot attends to
+    (read) and writes."""
+    pb = g.param_bytes
+    mm = float(_per_layer(g, g.matmul_flops).sum())
+    params = _per_layer(g, g.layer_params)
+    delta = _per_layer(g, lambda l: sum(2.0 * a * b
+                                        for a, b in g.projections(l)))
+    cache = float(_per_layer(g, g.cache_bytes).sum())
     flops = 0.0
-    nbytes = (L * g.layer_params + g.vocab * g.d + g.d) * pb
+    nbytes = (float(params.sum()) + g.head_params) * pb
     for user, pos in active:
         keys = pos + 1
-        flops += L * (2.0 * g.matmul_params + g.attn_flops(keys))
-        flops += 2.0 * g.d * g.vocab
-        nbytes += L * kv_row * (keys + 1)
+        flops += mm + sum(g.attn_flops(l, keys) for l in range(g.n_layers))
+        flops += g.lm_head_flops
+        nbytes += cache * (keys + 1)
         if delta_layers is not None and user >= 0:
-            k = len(delta_layers[user])
-            flops += k * 2.0 * g.matmul_params
-            nbytes += k * g.layer_params * pb
-    return flops, nbytes
+            ls = np.asarray(delta_layers[user])
+            flops += delta[ls].sum()
+            nbytes += params[ls].sum() * pb
+    return float(flops), float(nbytes)
 
 
-def delta_matmul_counts(g: Geometry, active: list, delta_layers
+def delta_matmul_counts(g, active: list, delta_layers
                         ) -> list[tuple[float, float]]:
     """(flops, bytes) of each ``base + delta`` product of one decode step:
     per layer and projection, the batch through the base weight plus each
@@ -146,8 +124,8 @@ def delta_matmul_counts(g: Geometry, active: list, delta_layers
         if user >= 0:
             per_layer[np.asarray(delta_layers[user])] += 1
     out = []
-    for n in per_layer:
-        for a, b in g.projections():
+    for l, n in enumerate(per_layer):
+        for a, b in g.projections(l):
             flops = 2.0 * B * a * b + 2.0 * n * a * b
             nbytes = (B * a + a * b + n * a * b + B * b) * pb
             out.append((flops, nbytes))

@@ -5,8 +5,26 @@ The cell, its configuration and its traffic mix are found by name:
 configuration is ``bench/configs/<config>.json``, the mix
 ``bench/traffic/<traffic>.json``, and the mix names its job,
 ``bench/jobs/<job>.py``.  A per-layer metric is ``bench/metrics/<name>.py``.
-A later cell, mix, job or metric is a new file and a new entry: nothing here
-changes.
+The configuration's block family (``program.family``, default ``dense``)
+names its plain reference, ``bench/reference/<family>.py``, and its work
+counts, ``bench/geometry/<family>.py`` (see ``family_module``).
+
+A new configuration brings, as new files:
+
+- ``bench/configs/<config>.json``: the published ``config.json`` keys
+  (``arch_config`` reads those it knows), ``name``, ``source``, and a
+  ``program`` block for what else the program's ``ArchConfig`` needs, by
+  field name (such as the experts one chip holds);
+- where its family is new, ``bench/reference/<family>.py`` (``Arch``,
+  ``class_loss``, ``lm_logits``, ``segments``) and
+  ``bench/geometry/<family>.py`` (``Geometry``, as ``bench/counts.py``
+  reads it);
+- ``bench/tests/tiny_sizes/configs/<config>.json``: the sizes its CPU
+  tests run at;
+
+and, as entries in ``BENCHMARK.json``, its cell in ``workloads`` and the
+cell's name in the ``workloads`` list of every metric its job reports.
+Nothing here changes.
 """
 from __future__ import annotations
 
@@ -14,8 +32,9 @@ import functools
 import importlib.util
 import json
 import math
+import sys
 import time
-from dataclasses import dataclass, field
+from dataclasses import MISSING, dataclass, field, fields
 from pathlib import Path
 from types import ModuleType
 from typing import Any, Callable, Optional
@@ -44,20 +63,29 @@ class WindowClosed(Exception):
 # finding things by name
 # ---------------------------------------------------------------------------
 
+def _shown(path: Path) -> str:
+    try:
+        return str(path.relative_to(ROOT))
+    except ValueError:
+        return str(path)
+
+
 def read_json(path: Path) -> dict:
     try:
         return json.loads(path.read_text())
     except FileNotFoundError:
-        raise BenchError(f"missing {path.relative_to(ROOT)}") from None
+        raise BenchError(f"missing {_shown(path)}") from None
 
 
 def load_module(path: Path) -> ModuleType:
     """Import a file of the benchmark by its path (names may hold dots)."""
     if not path.is_file():
-        raise BenchError(f"missing {path.relative_to(ROOT)}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        raise BenchError(f"missing {_shown(path)}")
+    name = (f"bench_{path.parent.name}_"
+            + path.stem.replace(".", "_").replace("-", "_"))
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod         # where a dataclass looks up its module
     spec.loader.exec_module(mod)
     return mod
 
@@ -100,6 +128,36 @@ def job_module(job: str, root: Path = ROOT) -> ModuleType:
 
 def metric_reader(name: str, root: Path = ROOT) -> Callable:
     return load_module(root / "bench" / "metrics" / f"{name}.py").read
+
+
+def family_module(config: dict, kind: str, root: Path = ROOT) -> ModuleType:
+    """``bench/<kind>/<family>.py`` of the configuration's block family:
+    ``kind`` is ``reference`` or ``geometry``."""
+    family = config.get("program", {}).get("family", "dense")
+    return load_module(root / "bench" / kind / f"{family}.py")
+
+
+def segment_slices(segments: list) -> dict[str, slice]:
+    """Each stacked segment's rows as a slice of the layer (mask) index:
+    ``segments`` is the family's ``[(name, layers), ...]`` in layer order."""
+    out, off = {}, 0
+    for name, n in segments:
+        out[name] = slice(off, off + int(n))
+        off += int(n)
+    return out
+
+
+def split_layers(segments: list, layers) -> dict:
+    """Layer indices (a user's delta layers) by the segment that holds
+    them: ``{name: (positions in layers, rows of the segment)}``."""
+    import numpy as np
+    layers = np.asarray(layers)
+    out = {}
+    for name, sl in segment_slices(segments).items():
+        pos = np.flatnonzero((layers >= sl.start) & (layers < sl.stop))
+        if pos.size:
+            out[name] = (pos, layers[pos] - sl.start)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -212,27 +270,76 @@ def now() -> float:
 _ACTS = {"gelu": "gelu_plain", "silu": "silu"}
 
 
+def _act(name: str) -> str:
+    if name not in _ACTS:
+        raise BenchError(f"hidden_act {name!r} is none the program has "
+                         f"(have {sorted(_ACTS)})")
+    return _ACTS[name]
+
+
+# ArchConfig field <- the published config.json keys that mean the same
+# thing (the first one present wins), and the field's type.
+_KEYS = (
+    ("n_layers", ("num_hidden_layers",), int),
+    ("d_model", ("hidden_size",), int),
+    ("n_heads", ("num_attention_heads",), int),
+    ("n_kv_heads", ("num_key_value_heads",), int),
+    ("head_dim", ("head_dim",), int),
+    ("d_ff", ("intermediate_size",), int),
+    ("vocab_size", ("vocab_size",), int),
+    ("mlp_act", ("hidden_act",), _act),
+    ("rope_theta", ("rope_theta",), float),
+    ("norm_eps", ("rms_norm_eps", "layer_norm_eps"), float),
+    ("tie_embeddings", ("tie_word_embeddings",), bool),
+    ("dtype", ("torch_dtype",), str),
+    ("n_experts", ("n_routed_experts", "num_local_experts", "num_experts"),
+     int),
+    ("n_shared_experts", ("n_shared_experts",), int),
+    ("top_k", ("num_experts_per_tok",), int),
+    ("first_dense", ("first_k_dense_replace",), int),
+    ("kv_lora_rank", ("kv_lora_rank",), int),
+    ("qk_rope_dim", ("qk_rope_head_dim",), int),
+    ("qk_nope_dim", ("qk_nope_head_dim",), int),
+    ("v_head_dim", ("v_head_dim",), int),
+    ("ssm_state", ("state_size", "mamba_d_state"), int),
+    ("ssm_expand", ("expand", "mamba_expand"), int),
+    ("ssm_groups", ("n_groups", "mamba_ngroups"), int),
+    ("ssm_conv", ("conv_kernel", "mamba_d_conv"), int),
+    ("ssm_chunk", ("chunk_size",), int),
+    ("sliding_window", ("sliding_window",), int),
+)
+# what a published file that leaves the key out means: no rotary positions
+# (the program's sinusoidal ones), an untied head, the dense family
+_ABSENT = {"family": "dense", "rope_theta": 0.0, "tie_embeddings": False}
+
+
 def arch_config(config: dict):
     """``repro.configs.base.ArchConfig`` for a configuration file: the
-    published ``config.json`` keys, plus ``program`` for what the program
-    needs besides (family, task head)."""
+    published ``config.json`` keys through ``_KEYS`` (a key's absence read
+    as the published convention: as many key/value heads as heads, a head
+    ``hidden_size / num_attention_heads`` wide), then the ``program``
+    block, which sets any field by its name and wins over the table."""
     from repro.configs.base import ArchConfig
-    d = config["hidden_size"]
-    heads = config["num_attention_heads"]
     prog = config.get("program", {})
-    return ArchConfig(
-        name=config["name"], family=prog.get("family", "dense"),
-        n_layers=config["num_hidden_layers"], d_model=d, n_heads=heads,
-        n_kv_heads=config.get("num_key_value_heads", heads),
-        head_dim=config.get("head_dim", d // heads),
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        mlp_act=_ACTS[config["hidden_act"]],
-        rope_theta=float(config.get("rope_theta", 0.0)),
-        norm_eps=float(config.get("rms_norm_eps",
-                                  config.get("layer_norm_eps", 1e-5))),
-        tie_embeddings=bool(config.get("tie_word_embeddings", False)),
-        task=prog.get("task", "lm"), n_classes=int(prog.get("n_classes", 0)),
-        dtype=config["torch_dtype"], source=config["source"])
+    unknown = sorted(set(prog) - {f.name for f in fields(ArchConfig)})
+    if unknown:
+        raise BenchError(f"program keys {unknown} name no ArchConfig field")
+    got = {"name": config["name"], "source": config["source"], **_ABSENT}
+    for name, keys, kind in _KEYS:
+        key = next((k for k in keys if config.get(k) is not None), None)
+        if key is not None:
+            got[name] = kind(config[key])
+    if "n_heads" in got:
+        got.setdefault("n_kv_heads", got["n_heads"])
+        if "d_model" in got:
+            got.setdefault("head_dim", got["d_model"] // got["n_heads"])
+    got.update(prog)
+    missing = sorted(f.name for f in fields(ArchConfig)
+                     if f.default is MISSING and f.name not in got)
+    if missing:
+        raise BenchError(f"{config['name']}: no value for {missing}; give "
+                         f"them in the configuration's program block")
+    return ArchConfig(**got)
 
 
 def program_seed(seed: int) -> int:

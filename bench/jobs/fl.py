@@ -20,7 +20,8 @@ import numpy as np
 
 from bench import gen
 from bench.harness import (Check, JobResult, WindowClosed, arch_config,
-                           device_barrier, now, peak_bytes, program_seed)
+                           device_barrier, family_module, now, peak_bytes,
+                           program_seed)
 from bench.reference.fl_round import (FLReference, Readings, checked_rounds,
                                       checked_schedule, compare, draw_cohorts,
                                       forced_masks, per_round)
@@ -40,16 +41,19 @@ def tokens_per_round(fl: dict) -> int:
             * int(fl["batch_size"]) * int(fl["seq_len"]))
 
 
-def _norms_fn():
-    """Per stacked block leaf, ‖(old − new)·scale‖ in f32, as one program."""
+def _norms_fn(segments: list):
+    """Per leaf of the stacked segments, ‖(old − new)·scale‖ in f32, as one
+    program, keyed as the reference keys it (``blocks/attn_wq``)."""
     import jax
     import jax.numpy as jnp
 
     def norms(new, old, scale):
-        return {f"blocks/{n}": jnp.linalg.norm(
-            (old["blocks"][n].astype(jnp.float32)
-             - new["blocks"][n].astype(jnp.float32)) * scale)
-            for n in old["blocks"]}
+        return {f"{seg}/{name}": jnp.linalg.norm(
+            (a.astype(jnp.float32) - b.astype(jnp.float32)) * scale)
+            for seg, _ in segments
+            for name, a, b in zip(leaf_names(old[seg]),
+                                  jax.tree.leaves(old[seg]),
+                                  jax.tree.leaves(new[seg]))}
     return jax.jit(norms)
 
 
@@ -93,7 +97,8 @@ def run(ctx) -> JobResult:
     # -- the checked rounds, through the window's own call ----------------
     R, forced = checked_rounds(L), checked_schedule(L)
     lr = float(fl["lr"])
-    norms = _norms_fn()
+    norms = _norms_fn(family_module(cell.config, "reference")
+                      .segments(cell.config))
     seen = Readings([], [], [], [], {})
     select = server.select_round
 

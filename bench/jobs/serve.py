@@ -24,7 +24,8 @@ import numpy as np
 
 from bench import gen
 from bench.harness import (Check, JobResult, WindowClosed, arch_config,
-                           device_barrier, now, peak_bytes)
+                           device_barrier, family_module, now, peak_bytes,
+                           split_layers)
 from bench.reference.decode import ServeReference, sample_requests
 from bench.weights import make_deltas, make_params
 
@@ -112,19 +113,24 @@ def window_numbers(loop: _Loop, requests, n_slots: int) -> dict:
                             and w0 <= loop.completions[j - n_slots] <= w1)}
 
 
-def build_store(cell, cfg, seed: int):
+def build_store(cell, cfg, shapes, seed: int):
     """The users' selected-layer deltas: drawn on the device, fetched once,
-    held by the program's ``DeltaStore``."""
-    from repro.models.model import _block_shapes
+    held by the program's ``DeltaStore``.  Each stacked segment of the
+    family draws rows of its own leaves for a user's k layers, and a layer
+    takes its rows from the segment that holds it."""
     from repro.serve import DeltaRecord, DeltaStore
     mix = cell.mix
     users, k = int(mix["users"]), int(mix["delta_layers"])
-    rows = make_deltas(_block_shapes(cfg, "dense"), users, k, seed)
-    layers = gen.delta_layers(mix, cfg.n_layers, seed)
+    segments = family_module(cell.config, "reference").segments(cell.config)
+    rows = {seg: make_deltas({n: s.shape[1:] for n, s in shapes[seg].items()},
+                             users, k, seed)
+            for seg, _ in segments}
+    layers = gen.delta_layers(mix, sum(n for _, n in segments), seed)
     store = DeltaStore(cfg)
     for u in range(users):
-        store.put(u, DeltaRecord(layers=layers[u], segments={"blocks": (
-            layers[u], {n: v[u] for n, v in rows.items()})}))
+        store.put(u, DeltaRecord(layers=layers[u], segments={
+            seg: (idx, {n: v[u][pos] for n, v in rows[seg].items()})
+            for seg, (pos, idx) in split_layers(segments, layers[u]).items()}))
     return store, layers, rows
 
 
@@ -143,7 +149,7 @@ def run(ctx) -> JobResult:
     params = make_params(shapes, seed)
     store = layers = rows = None
     if mode == "delta":
-        store, layers, rows = build_store(cell, cfg, seed)
+        store, layers, rows = build_store(cell, cfg, shapes, seed)
     max_seq = int(mix["prompt"]["max"]) + int(mix["output"]["max"]) + 1
     server = SlotServer(model, params, slots, max_seq, mode=mode, store=store,
                         capacity=int(mix.get("overlay_capacity", 0)))

@@ -2,7 +2,7 @@
 masked SGD steps of the selected layers require
 (bench.counts.masked_update_bytes) at HBM peak, over the kernel's summed
 device time in the traced window.  Nothing to read without the kernel."""
-from bench.counts import Geometry, masked_update_bytes
+from bench.counts import geometry, masked_update_bytes
 from bench.peaks import roofline_seconds
 
 KERNEL = "masked_sgd_update_2d"
@@ -14,7 +14,7 @@ def read(run):
     t = run.trace.kernel_seconds(KERNEL)
     if t <= 0:
         return None
-    g = Geometry.from_config(run.context["config"])
+    g = geometry(run.context["config"])
     tau = int(run.context["fl"]["local_steps"])
     nbytes = sum(masked_update_bytes(g, r["masks"], tau)
                  for r in run.context["rounds"])

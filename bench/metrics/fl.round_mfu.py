@@ -1,7 +1,7 @@
 """The whole round's share of the chip's bf16 peak: the operations the
 rounds completed in the traced window require (bench.counts.fl_round_flops)
 over the window's length times the peak."""
-from bench.counts import Geometry, fl_round_flops
+from bench.counts import fl_round_flops, geometry
 from bench.peaks import peaks
 
 
@@ -9,7 +9,7 @@ def read(run):
     if run.trace is None or run.context.get("job") != "fl":
         return None
     fl = run.context["fl"]
-    g = Geometry.from_config(run.context["config"])
+    g = geometry(run.context["config"])
     flops = sum(fl_round_flops(g, r["masks"], r["probed"],
                                tau=int(fl["local_steps"]),
                                batch=int(fl["batch_size"]),

@@ -2,14 +2,14 @@
 required operations over peak FLOP/s and the required bytes over peak
 bandwidth (bench.counts.decode_step_counts), summed over the steps that
 started in the traced window, over the window's length."""
-from bench.counts import Geometry, decode_step_counts
+from bench.counts import decode_step_counts, geometry
 from bench.peaks import roofline_seconds
 
 
 def read(run):
     if run.trace is None or run.context.get("job") != "serve":
         return None
-    g = Geometry.from_config(run.context["config"])
+    g = geometry(run.context["config"])
     layers = run.context["layers"]
     least = sum(roofline_seconds(*decode_step_counts(g, act, layers),
                                  run.device_kind)[0]

@@ -3,7 +3,7 @@ its roofline: per product the larger of required operations over peak and
 required bytes over bandwidth, the base weight read once and only the
 active users' delta rows (bench.counts.delta_matmul_counts), over the
 kernel's summed device time in the traced window."""
-from bench.counts import Geometry, delta_matmul_counts
+from bench.counts import delta_matmul_counts, geometry
 from bench.peaks import roofline_seconds
 
 KERNEL = "base_delta_matmul_2d"
@@ -16,7 +16,7 @@ def read(run):
     t = run.trace.kernel_seconds(KERNEL)
     if layers is None or t <= 0:
         return None
-    g = Geometry.from_config(run.context["config"])
+    g = geometry(run.context["config"])
     least = sum(roofline_seconds(f, b, run.device_kind)[0]
                 for _, act in run.context["steps"]
                 for f, b in delta_matmul_counts(g, act, layers))

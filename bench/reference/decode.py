@@ -5,7 +5,9 @@ Greedy decoding serves the token with the largest logit.  So for every
 served token, the reference runs the whole sequence (prompt and served
 tokens) once, causally, and reads how far the served token's logit lies
 below the reference's best at that position.  The widest such gap over a
-sample of the finished requests is the number compared.
+sample of the finished requests is the number compared.  The
+configuration's family (``bench/reference/<family>.py``) gives the forward
+and the stacked segments that a delta layer's rows lie in.
 """
 from __future__ import annotations
 
@@ -16,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.gen import rng_for
-from bench.reference.transformer import Arch, lm_logits
+from bench.harness import family_module, split_layers
 from bench.weights import leaf_names, make_params
 
 
@@ -42,24 +44,30 @@ class ServeReference:
                  layers: Optional[list] = None, rows: Optional[dict] = None):
         """``shapes``: the program's parameter tree of ShapeDtypeStructs (the
         benchmark makes the same values from ``seed``); ``layers``/``rows``:
-        the users' delta layers and ``{leaf: (users, k, *shape)}`` rows."""
-        self.a = Arch.from_config(config)
+        the users' delta layers and, per segment, ``{leaf: (users, k,
+        *shape)}`` rows (entry j for the user's j-th layer)."""
+        family = family_module(config, "reference")
+        self.a = family.Arch.from_config(config)
+        self.segments = family.segments(config)
         self.max_len = max_len
         names = leaf_names(shapes)
         self.params = {n: jnp.asarray(v, jnp.float32) for n, v in
                        zip(names, jax.tree.leaves(make_params(shapes, seed)))}
         self.layers, self.rows = layers, rows
-        self._fwd = jax.jit(lambda p, t, lowp=None: lm_logits(p, t, self.a, lowp)[0],
-                            static_argnums=2)
+        self._fwd = jax.jit(
+            lambda p, t, lowp=None: family.lm_logits(p, t, self.a, lowp)[0],
+            static_argnums=2)
 
     def private(self, user: int) -> dict:
         if user < 0 or self.layers is None:
             return self.params
         p = dict(self.params)
-        idx = jnp.asarray(self.layers[user])
-        for name, rows in self.rows.items():
-            key = f"blocks/{name}"
-            p[key] = p[key].at[idx].add(jnp.asarray(rows[user]))
+        for seg, (pos, idx) in split_layers(self.segments,
+                                            self.layers[user]).items():
+            for name, rows in self.rows[seg].items():
+                key = f"{seg}/{name}"
+                p[key] = p[key].at[jnp.asarray(idx)].add(
+                    jnp.asarray(rows[user][pos]))
         return p
 
     def logits(self, prompt, served, user: int, lowp=None) -> np.ndarray:

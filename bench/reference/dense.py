@@ -14,7 +14,8 @@ Matrix products go through :func:`mm`, at ``precision="highest"`` in f32;
 ``lowp`` (a dtype such as ``jnp.float8_e4m3fn``) rounds both operands of
 every product to it first: the control that a lower precision must fail.
 Parameters are a flat dict keyed by the program's leaf paths
-(``embed/tok``, ``blocks/attn_wq``, ...), stacked per layer.
+(``embed/tok``, ``blocks/attn_wq``, ...), stacked per layer in one
+segment, ``blocks`` (``segments``).
 """
 from __future__ import annotations
 
@@ -54,6 +55,11 @@ class Arch:
                    eps=float(c.get("rms_norm_eps", c.get("layer_norm_eps", 1e-5))),
                    causal=prog.get("task", "lm") == "lm",
                    n_classes=int(prog.get("n_classes", 0)))
+
+
+def segments(c: dict) -> list:
+    """The stacked parameter segments in layer order: (name, layers)."""
+    return [("blocks", c["num_hidden_layers"])]
 
 
 def mm(a, b, lowp=None):

@@ -6,7 +6,9 @@ probe batch (all L layers); the (P1) selection by iterated conditional
 modes under each client's budget, warm-started from the client's previous
 mask; τ masked SGD steps per client; Eq.(5)-(7) aggregation and the global
 step.  Parameters are stored in the configuration's dtype between steps
-(they are bf16 parameters), and every step is computed in f32.
+(they are bf16 parameters), and every step is computed in f32.  The
+configuration's family (``bench/reference/<family>.py``) gives the loss
+and the stacked segments whose rows are the round's layers.
 
 The cohort draw replays the program's documented sampling: a
 ``numpy.random.RandomState(seed)`` drawing ``choice(N, cohort,
@@ -26,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference.transformer import Arch, class_loss
+from bench.harness import family_module, segment_slices
 
 
 # -- (P1): λ-regularised layer selection, §4.2 -------------------------------
@@ -113,27 +115,40 @@ class Readings:
 
 class FLReference:
     def __init__(self, config: dict, fl: dict, lowp=None):
-        self.a = Arch.from_config(config)
+        self.family = family_module(config, "reference")
+        self.a = self.family.Arch.from_config(config)
+        self.segments = self.family.segments(config)
+        self.slices = segment_slices(self.segments)
         self.fl = fl
         self.lowp = lowp
         self.store = jnp.dtype(config["torch_dtype"])
         self._grad = jax.jit(jax.value_and_grad(self._loss, argnums=0))
 
     def _loss(self, blocks, frozen, batch):
-        return class_loss({**frozen, **blocks}, batch, self.a, self.lowp)
+        return self.family.class_loss({**frozen, **blocks}, batch, self.a,
+                                      self.lowp)
 
     def _stored(self, x):
         return x.astype(self.store).astype(jnp.float32)
 
+    def _layer_rows(self, per_layer, leaf: str, ndim: int):
+        """A per-layer vector's entries for a stacked leaf's rows, shaped
+        to broadcast over the leaf."""
+        rows = per_layer[self.slices[leaf.split("/", 1)[0]]]
+        return rows.reshape((-1,) + (1,) * (ndim - 1))
+
     @partial(jax.jit, static_argnums=0)
     def _sgd(self, blocks, grads, mask, lr):
         """θ_l ← θ_l − lr·m_l·g_l, stored back in the parameter dtype."""
-        return {k: self._stored(v - lr * mask.reshape((-1,) + (1,) * (v.ndim - 1))
+        return {k: self._stored(v - lr * self._layer_rows(mask, k, v.ndim)
                                 * grads[k]) for k, v in blocks.items()}
 
     def layer_sq_norms(self, grads: dict) -> np.ndarray:
-        return np.asarray(sum(jnp.sum(jnp.square(g).reshape(g.shape[0], -1), 1)
-                              for g in grads.values()), np.float64)
+        """Σ g² of each layer, in layer order over the segments."""
+        return np.asarray(jnp.concatenate([
+            sum(jnp.sum(jnp.square(g).reshape(g.shape[0], -1), 1)
+                for k, g in grads.items() if k.split("/", 1)[0] == seg)
+            for seg, _ in self.segments]), np.float64)
 
     def run(self, params: dict, task, cohorts: list, budgets, rounds: int,
             forced: dict | None = None) -> Readings:
@@ -145,11 +160,12 @@ class FLReference:
         fl, forced = self.fl, forced or {}
         lr, lam, tau = float(fl["lr"]), float(fl["lam"]), int(fl["local_steps"])
         B, period = int(fl["batch_size"]), int(fl["selection_period"])
-        L = self.a.n_layers
+        L = sum(n for _, n in self.segments)
         with jax.default_matmul_precision("highest"):
             params = {k: jnp.asarray(v, jnp.float32) for k, v in params.items()}
-            frozen = {k: v for k, v in params.items() if not k.startswith("blocks/")}
-            blocks = {k: v for k, v in params.items() if k.startswith("blocks/")}
+            blocks = {k: v for k, v in params.items()
+                      if k.split("/", 1)[0] in self.slices}
+            frozen = {k: v for k, v in params.items() if k not in blocks}
             theta0 = blocks
             warm: dict[int, np.ndarray] = {}
             stats: dict[int, np.ndarray] = {}
@@ -191,9 +207,9 @@ class FLReference:
                 W = eq7_weights(masks, task.sizes[cohort])
                 new = {}
                 for n, v in blocks.items():
-                    upd = sum(jnp.asarray(W[r], jnp.float32).reshape(
-                        (-1,) + (1,) * (v.ndim - 1)) * deltas[r][n]
-                        for r in range(len(cohort)))
+                    upd = sum(self._layer_rows(jnp.asarray(W[r], jnp.float32),
+                                               n, v.ndim) * deltas[r][n]
+                              for r in range(len(cohort)))
                     new[n] = self._stored(v - lr * upd)
                 out.updates.append({n: float(jnp.linalg.norm(
                     (blocks[n] - new[n]) / lr)) for n in blocks})

@@ -4,22 +4,27 @@ import numpy as np
 import pytest
 
 from bench import counts
-from bench.counts import Geometry
+from bench.geometry.dense import Geometry
 
 # d 8, 2 heads of 4 (1 kv head), ff 16, SwiGLU, vocab 32, 2 layers
 G = Geometry(n_layers=2, d=8, heads=2, kv_heads=1, head_dim=4, ff=16,
              vocab=32, gated=True, n_classes=3)
+P = 64 + 32 + 32 + 64 + 256 + 128       # a layer's product weights
 
 
 def test_projection_and_layer_params_by_hand():
     # wq 8x8, wk 8x4, wv 8x4, wo 8x8, wi 8x32, wo 16x8
-    assert G.matmul_params == 64 + 32 + 32 + 64 + 256 + 128
-    assert G.layer_params == G.matmul_params + 16
+    for l in range(2):
+        assert sum(a * b for a, b in G.projections(l)) == P
+        assert G.matmul_flops(l) == 2 * P
+        assert G.layer_params(l) == P + 16
+        assert G.cache_bytes(l) == 2 * 1 * 4 * 2
+    assert G.head_params == 32 * 8 + 8
 
 
 def test_fl_round_flops_by_hand():
     masks = np.array([[0, 1], [0, 0]], np.float32)   # client 0 trains layer 1
-    P, seq, tau, batch = G.matmul_params, 4, 2, 3
+    seq, tau, batch = 4, 2, 3
     fwd_tok = 2 * P + 4 * seq * 2 * 4
     bwd_tok = 2 * P + 8 * seq * 2 * 4
     upd_tok = tau * batch * seq
@@ -38,20 +43,19 @@ def test_fl_round_flops_by_hand():
 
 def test_kernel_bytes_by_hand():
     masks = np.array([[1, 1], [0, 1]], np.float32)
-    assert counts.masked_update_bytes(G, masks, tau=2) == 2 * 3 * G.layer_params * 3 * 2
-    assert counts.layer_grad_norm_bytes(G, probed=3) == 3 * 2 * G.layer_params * 2
+    assert counts.masked_update_bytes(G, masks, tau=2) == 2 * 3 * (P + 16) * 3 * 2
+    assert counts.layer_grad_norm_bytes(G, probed=3) == 3 * 2 * (P + 16) * 2
 
 
 def test_decode_step_counts_by_hand():
     layers = [np.array([0]), np.array([0, 1])]
     active = [(0, 3), (1, 0), (-1, 5)]
-    P = G.matmul_params
     flops = sum(2 * (2 * P + 4 * (p + 1) * 8) + 2 * 8 * 32 for _, p in active)
     flops += (1 + 2) * 2 * P
     kv = 2 * 1 * 4 * 2
-    nbytes = (2 * G.layer_params + 32 * 8 + 8) * 2
+    nbytes = (2 * (P + 16) + 32 * 8 + 8) * 2
     nbytes += sum(2 * kv * (p + 2) for _, p in active)
-    nbytes += (1 + 2) * G.layer_params * 2
+    nbytes += (1 + 2) * (P + 16) * 2
     f, b = counts.decode_step_counts(G, active, layers)
     assert f == pytest.approx(flops) and b == pytest.approx(nbytes)
     f0, b0 = counts.decode_step_counts(G, active, None)
@@ -62,7 +66,7 @@ def test_delta_matmul_counts_by_hand():
     layers = [np.array([0]), np.array([0, 1])]
     out = counts.delta_matmul_counts(G, [(0, 1), (1, 2)], layers)
     assert len(out) == 2 * 6
-    a, b = G.projections()[0]                     # layer 0, wq: 2 entries
+    a, b = G.projections(0)[0]                    # layer 0, wq: 2 entries
     assert out[0] == (2.0 * 2 * a * b + 2.0 * 2 * a * b,
                       (2 * a + a * b + 2 * a * b + 2 * b) * 2)
     assert out[6][0] == 2.0 * 2 * a * b + 2.0 * 1 * a * b   # layer 1: 1 entry

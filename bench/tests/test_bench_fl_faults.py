@@ -8,7 +8,9 @@ import pytest
 from bench.tests.tiny import run_tiny, tiny_cell
 
 
-@pytest.mark.parametrize("cell", ["xlmr-fl-select", "xlmr-fl-period5"])
+@pytest.mark.parametrize("cell", [
+    "xlmr-fl-select",
+    pytest.param(("xlmr-base", "fl-period5"), id="xlmr-fl-period5")])
 def test_sound_run_is_correct(cell):
     r = run_tiny(cell, seed=21)
     assert r.correct, [(c.name, c.value, c.limit) for c in r.checks]

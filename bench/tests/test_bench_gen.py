@@ -164,4 +164,4 @@ def test_tiny_cells_keep_the_committed_mix():
     committed = harness.load_cell("xlmr-fl-select")
     assert cell.mix["limits"] == committed.mix["limits"]
     assert cell.mix["selection_period"] == 1
-    assert tiny_cell("xlmr-fl-period5").mix["selection_period"] == 5
+    assert tiny_cell(("xlmr-base", "fl-period5")).mix["selection_period"] == 5

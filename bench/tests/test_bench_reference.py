@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from bench import harness
-from bench.reference import transformer as R
+from bench.reference import dense as R
 from bench.tests.tiny import tiny_cell
 from bench.weights import leaf_names, make_params
 

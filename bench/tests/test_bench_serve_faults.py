@@ -8,8 +8,9 @@ import pytest
 from bench.tests.tiny import run_tiny, tiny_cell
 
 
-@pytest.mark.parametrize("workload", ["smollm-serve-delta",
-                                      "smollm-serve-base"])
+@pytest.mark.parametrize("workload", [
+    "smollm-serve-delta",
+    pytest.param(("smollm-360m", "serve-chat-base"), id="smollm-serve-base")])
 def test_sound_run_is_correct(workload):
     r = run_tiny(workload, seed=31)
     assert r.correct, [(c.name, c.value, c.limit) for c in r.checks]

@@ -1,54 +1,48 @@
 """Tiny cells for the CPU tests: the benchmark's jobs driven end to end at a
-size a test run holds, with the harness's look for a chip skipped."""
+size a test run holds, with the harness's look for a chip skipped.
+
+The tiny sizes are data, found by name: a configuration's in
+``tiny_sizes/configs/<config>.json``, a job's in ``tiny_sizes/jobs/<job>.json``.
+A cell is a workload of ``BENCHMARK.json`` or a (configuration, traffic)
+pair, for a committed mix that is not a cell."""
 from __future__ import annotations
 
-import json
 import time
 from pathlib import Path
+from typing import Union
 
 from bench import harness
 
-BENCH = Path(__file__).resolve().parents[1]
 
-TINY_MODELS = {
-    "xlmr-base": {"hidden_size": 64, "num_hidden_layers": 3,
-                  "num_attention_heads": 4, "intermediate_size": 128,
-                  "vocab_size": 512, "n_clients": 12, "cohort_size": 3,
-                  "batch_size": 2},
-    "smollm-360m": {"hidden_size": 256, "num_hidden_layers": 8,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "intermediate_size": 384, "vocab_size": 4096},
-}
-TINY_MIXES = {
-    "fl": {"seq_len": 16, "test_samples": 4},
-    "serve": {"users": 4, "prompt": {"median": 6, "sigma": 0.8, "min": 2,
-                                     "max": 16},
-              "output": {"median": 4, "sigma": 0.8, "min": 2, "max": 8},
-              "callers": 3, "overlay_capacity": 3, "requests_per_caller": 400,
-              "prime_stride": 3, "check_tokens": 24},
-}
+def _sizes(root: Path, kind: str, name: str) -> dict:
+    return harness.read_json(root / "bench" / "tests" / "tiny_sizes" / kind
+                             / f"{name}.json")
 
 
-CELLS = {"xlmr-fl-select": ("xlmr-base", "fl-select"),
-         "xlmr-fl-period5": ("xlmr-base", "fl-period5"),
-         "smollm-serve-delta": ("smollm-360m", "serve-chat-delta"),
-         "smollm-serve-base": ("smollm-360m", "serve-chat-base")}
+def tiny_config(name: str, root: Path = harness.ROOT) -> dict:
+    """A configuration as committed, at its tiny sizes."""
+    return {**harness.read_json(root / "bench" / "configs" / f"{name}.json"),
+            **_sizes(root, "configs", name)}
 
 
-def tiny_cell(cell: str) -> harness.Cell:
+def tiny_cell(cell: Union[str, tuple[str, str]]) -> harness.Cell:
     """A configuration under a traffic mix, as committed, at tiny widths
     and loads (every other parameter, limits included, as committed)."""
-    config_name, traffic = CELLS[cell]
-    config = json.loads((BENCH / "configs" / f"{config_name}.json").read_text())
-    config.update(TINY_MODELS[config_name])
-    mix = json.loads((BENCH / "traffic" / f"{traffic}.json").read_text())
-    mix.update(TINY_MIXES[mix["job"]])
-    return harness.Cell(name=cell, chips=1, config=config, mix=mix,
-                        end_to_end=[], per_layer=[])
+    if isinstance(cell, str):
+        w = next(w for w in harness.read_json(
+            harness.ROOT / "BENCHMARK.json")["workloads"] if w["name"] == cell)
+        name, config_name, traffic = cell, w["config"], w["traffic"]
+    else:
+        (config_name, traffic), name = cell, ".".join(cell)
+    mix = harness.read_json(harness.ROOT / "bench" / "traffic"
+                            / f"{traffic}.json")
+    mix.update(_sizes(harness.ROOT, "jobs", mix["job"]))
+    return harness.Cell(name=name, chips=1, config=tiny_config(config_name),
+                        mix=mix, end_to_end=[], per_layer=[])
 
 
-def run_tiny(cell: str, seed: int = 3, seconds: float = 1.0,
-             control=None) -> harness.JobResult:
+def run_tiny(cell: Union[str, tuple[str, str]], seed: int = 3,
+             seconds: float = 1.0, control=None) -> harness.JobResult:
     from bench.run import JobContext
     cell = tiny_cell(cell)
     ctx = JobContext(cell=cell, seed=seed, seconds=seconds, trace=False,
